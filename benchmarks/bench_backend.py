@@ -14,8 +14,8 @@ Gates (``--check``, on by default):
   * >= 2x tokens/sec and lower p95 latency for continuous batching.
 
 The results JSON includes the backend telemetry (step counts, slot
-occupancy, KV-block peaks) and the roofline-derived utilization of the
-prefill/decode step functions per workload mix (``launch/roofline.py``).
+occupancy, KV-block peaks).  It runs the CPU smoke preset, so its times
+are host timings, not device numbers.
 """
 from __future__ import annotations
 
@@ -118,15 +118,6 @@ def run(check: bool = True, quick: bool = False) -> Dict[str, Any]:
         mc = _measure(cont, reqs)
         identical = ms["rows"] == mc["rows"]
         speedup = mc["tokens_per_s"] / ms["tokens_per_s"]
-        roofline = cont.backend_roofline()
-        steps = {k: roofline[k] for k in roofline}
-        bs = mc["backend"]
-        n_steps = bs["prefill_steps"] + bs["decode_steps"]
-        util = 0.0
-        if n_steps and roofline:
-            util = sum(
-                roofline[k]["mfu_bound"] * bs[f"{k}_steps"]
-                for k in ("prefill", "decode") if k in roofline) / n_steps
         results[mix_name] = {
             "requests": len(reqs),
             "static": {k: v for k, v in ms.items() if k != "rows"},
@@ -135,14 +126,6 @@ def run(check: bool = True, quick: bool = False) -> Dict[str, Any]:
             "credits_conserved": ms["credits"] == mc["credits"],
             "tokens_per_s_speedup": speedup,
             "p95_ratio": mc["p95_ms"] / ms["p95_ms"],
-            "roofline_utilization_per_step_mix": {
-                "step_mix": {"prefill_steps": bs["prefill_steps"],
-                             "decode_steps": bs["decode_steps"],
-                             "decode_slot_occupancy":
-                                 bs["decode_slot_occupancy"]},
-                "mix_weighted_mfu_bound": util,
-                "per_step_kind": steps,
-            },
         }
         for name, m in (("static", ms), ("continuous", mc)):
             table.append({
@@ -151,8 +134,6 @@ def run(check: bool = True, quick: bool = False) -> Dict[str, Any]:
                 "p50_ms": round(m["p50_ms"], 1),
                 "p95_ms": round(m["p95_ms"], 1),
                 "identical": identical,
-                "util%": (round(100 * util, 2)
-                          if name == "continuous" else ""),
             })
         if check:
             assert identical, f"{mix_name}: result rows differ"
@@ -164,7 +145,7 @@ def run(check: bool = True, quick: bool = False) -> Dict[str, Any]:
             assert mc["p95_ms"] < ms["p95_ms"], \
                 f"{mix_name}: continuous p95 not lower"
     print(fmt_table(table, ["mix", "backend", "tok/s", "p50_ms", "p95_ms",
-                            "identical", "util%"]))
+                            "identical"]))
     path = save_result("bench_backend", results)
     print(f"saved {path}")
     return results

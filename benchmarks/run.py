@@ -80,6 +80,8 @@ def main(argv=None) -> int:
                     help="skip the real-engine serving benchmark (slow)")
     args = ap.parse_args(argv)
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     from benchmarks import (bench_adaptive, bench_agg_shortcircuit,
                             bench_cascade, bench_concurrent, bench_http,
                             bench_hybrid_join, bench_index,

@@ -242,6 +242,13 @@ def get_config(arch: str) -> ModelConfig:
     return mod.CONFIG
 
 
+def depth_cut(arch: str, num_layers: int) -> ModelConfig:
+    """``arch``'s published config with every width kept and only
+    ``num_layers`` layers: the depth cut that fits one chip.  The name is
+    kept, so requests route and price as the published model."""
+    return dataclasses.replace(get_config(arch), num_layers=num_layers)
+
+
 def get_smoke_config(arch: str) -> ModelConfig:
     """Reduced config of the same family for CPU smoke tests."""
     mod = importlib.import_module(f"repro.configs.{_MODULE_FOR[arch]}")

@@ -31,6 +31,7 @@ from typing import (Any, Callable, Dict, List, Optional, Sequence, Tuple,
 
 import numpy as np
 
+from repro.configs.base import ModelConfig
 from repro.inference.backend import (CLASSIFY, COMPLETE, EMBED, SCORE,
                                      Request, Result)
 from repro.inference.pipeline import (PipelineConfig, RequestPipeline,
@@ -233,23 +234,34 @@ def make_simulated_client(*, seed: int = 0, default_model: str = "oracle-70b",
                         pipeline=_make_pipeline(pipelined, pipeline))
 
 
-def make_engine_client(archs: Sequence[str] = ("proxy-8b", "oracle-70b"), *,
+def make_engine_client(archs: Sequence[Union[str, ModelConfig]] = (
+                           "proxy-8b", "oracle-70b"), *,
                        seed: int = 0, replicas: int = 1,
                        default_model: Optional[str] = None,
                        pipelined: bool = False,
                        pipeline: Union[None, PipelineConfig,
                                        RequestPipeline] = None,
                        backend: str = "auto") -> CortexClient:
-    """Convenience: a CortexClient over real JAX engines (smoke-size).
+    """Convenience: a CortexClient over real JAX engines.
+
+    Each entry of ``archs`` is a model name (served by its CPU smoke
+    preset) or a `ModelConfig` (served as configured, e.g. published
+    widths cut in depth), routed under the config's name.  Replica ``i``
+    of a model lives on ``jax.devices()[i]`` (modulo the device count)
+    and all replicas share the seed, i.e. one set of weights.
     ``backend`` pins the engines' decode backend ("auto" picks continuous
     batching wherever the architecture supports the paged KV cache)."""
+    import jax
+
     from repro.inference.engine import JaxInferenceEngine
+    devices = jax.devices()
+    names = [a if isinstance(a, str) else a.name for a in archs]
     sched = Scheduler()
-    for arch in archs:
+    for arch, name in zip(archs, names):
         for rep in range(replicas):
             sched.register(JaxInferenceEngine(
-                arch, engine_id=f"{arch}#{rep}", seed=seed + rep,
-                backend=backend))
-    return CortexClient(sched, default_model=default_model or archs[-1],
-                        proxy_model=archs[0],
+                arch, engine_id=f"{name}#{rep}", seed=seed,
+                device=devices[rep % len(devices)], backend=backend))
+    return CortexClient(sched, default_model=default_model or names[-1],
+                        proxy_model=names[0],
                         pipeline=_make_pipeline(pipelined, pipeline))

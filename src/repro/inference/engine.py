@@ -7,8 +7,10 @@ This is the Cortex Platform "Inference Engine" (paper §2) adapted to TPU:
     EOS and queued work is admitted at *every* decode step, with long
     prompts chunk-prefilled between steps (``inference/continuous.py``).
     SCORE and COMPLETE ride this path; CLASSIFY/EMBED (single forward
-    passes) and non-attention architectures use the static path below,
-    with **bit-identical results** either way;
+    passes) and non-attention architectures use the static path below.
+    On the CPU either path gives the same text, token counts and
+    credits, and SCOREs agree to float32 rounding; on the TPU they agree
+    to bfloat16 rounding (``continuous.py``'s determinism contract);
   * static-shape batch fallback: one blocking prefill+decode call per
     batch, finished sequences retiring early from the decode loop;
   * bucketed prefill (power-of-two lengths) and bucketed decode batch
@@ -30,12 +32,14 @@ from __future__ import annotations
 
 import hashlib
 import time
-from typing import Any, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import SingleDeviceSharding
 
+from repro.configs import base as cfgs
 from repro.inference import tokenizer as tok
 from repro.inference.backend import (CLASSIFY, COMPLETE, EMBED, SCORE,
                                      EngineFailure, Request, Result,
@@ -57,26 +61,41 @@ def _hash_embed(key: str, shape, scale=0.1) -> np.ndarray:
 
 
 class JaxInferenceEngine:
-    """Hosts one model and serves batched requests."""
+    """Hosts one model on one device and serves batched requests.
 
-    def __init__(self, arch: str, *, engine_id: str = "", smoke: bool = True,
+    ``arch`` is a model name (its published config, or the CPU smoke
+    preset with ``smoke=True``) or a `ModelConfig` such as a depth cut of
+    a published config; the config's ``name`` then routes and prices
+    requests.  Params, the KV pool and every step input are committed to
+    ``device`` (default: the first device), so the engine's jitted steps
+    run there."""
+
+    def __init__(self, arch: Union[str, cfgs.ModelConfig], *,
+                 engine_id: str = "", smoke: bool = True,
+                 device: Optional[jax.Device] = None,
                  max_batch: int = 8, max_seq: int = 384, seed: int = 0,
                  failure_rate: float = 0.0, straggle_s: float = 0.0,
                  backend: str = "auto", block_size: int = 32,
                  kv_blocks: Optional[int] = None, prefill_chunk: int = 32,
                  decode_impl: str = "auto"):
         from repro.inference import continuous as cb
-        self.arch = arch
-        self.engine_id = engine_id or f"{arch}#0"
         self.model = model_zoo.build(arch, smoke=smoke)
         self.cfg = self.model.cfg
+        self.arch = arch if isinstance(arch, str) else self.cfg.name
+        self.engine_id = engine_id or f"{self.arch}#0"
         assert self.cfg.vocab_size >= tok.VOCAB_SIZE
+        self.device = device if device is not None else jax.devices()[0]
         self.max_batch = max_batch
         self.max_seq = max_seq
         self.failure_rate = failure_rate
         self.straggle_s = straggle_s
         self._rng = np.random.default_rng(seed + 17)
-        self.params = self.model.init_params(jax.random.PRNGKey(seed))
+        # one jitted init on the engine's device: normal, scale and cast
+        # fuse, so no float32 copy of a stacked [layers, ...] leaf exists
+        self.params = jax.jit(
+            self.model.init_params,
+            out_shardings=SingleDeviceSharding(self.device))(
+                jax.random.PRNGKey(seed))
         self._jit_cache: Dict[Any, Any] = {}
         self.jit_compiles = 0      # distinct jit entries (compile proxy)
         # decode backend: continuous batching wherever the architecture
@@ -84,8 +103,8 @@ class JaxInferenceEngine:
         if backend == "auto":
             backend = "continuous" if cb.supports(self.cfg) else "static"
         elif backend == "continuous" and not cb.supports(self.cfg):
-            raise ValueError(f"{arch}: architecture does not support the "
-                             "continuous paged-KV backend")
+            raise ValueError(f"{self.arch}: architecture does not support "
+                             "the continuous paged-KV backend")
         elif backend not in ("continuous", "static"):
             raise ValueError(f"unknown backend {backend!r}")
         self.backend = backend
@@ -140,6 +159,10 @@ class JaxInferenceEngine:
             extra["positions"] = pos
         return extra
 
+    def put(self, x):
+        """Commit a host array (or pytree of them) to this engine's device."""
+        return jax.device_put(x, self.device)
+
     def _jit(self, key, fn, donate=()):
         if key not in self._jit_cache:
             self.jit_compiles += 1
@@ -163,8 +186,8 @@ class JaxInferenceEngine:
 
         fn = self._jit(("prefill", B, L, smax, tuple(sorted(extra))),
                        prefill_fn)
-        logits, cache = fn(self.params, jnp.asarray(toks), jnp.asarray(lens),
-                           {k: jnp.asarray(v) for k, v in extra.items()})
+        logits, cache = fn(self.params, self.put(toks), self.put(lens),
+                           self.put(extra))
         return logits, cache, lens, L
 
     # ------------------------------------------------------------------
@@ -267,24 +290,30 @@ class JaxInferenceEngine:
             toks[i, :len(s)] = s
             msk[i, :len(m)] = m
 
-        def lp_fn(params, tokens, mask):
-            batch = {"tokens": tokens}
-            if self.cfg.frontend == "frames":
-                batch["frames"] = jnp.zeros(
-                    (tokens.shape[0], self.cfg.encoder_seq, self.cfg.d_model),
-                    jnp.bfloat16)
-            out = self.model.apply(params, batch, mode="train", remat=False)
-            logits = self.model.logits_of(params, out["hidden"])
-            logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-            # hidden[t] predicts token[t+1]
-            tgt = tokens[:, 1:]
-            lp = jnp.take_along_axis(logp[:, :-1], tgt[..., None], -1)[..., 0]
-            m = mask[:, 1:]
-            return jnp.sum(lp * m, 1) / jnp.maximum(jnp.sum(m, 1), 1.0)
-
-        fn = self._jit(("seqlp", B, L), lp_fn)
-        lps = np.asarray(fn(self.params, jnp.asarray(toks), jnp.asarray(msk)))
+        fn = self._jit(("seqlp", B, L), self._seqlp_fn)
+        lps = np.asarray(fn(self.params, self.put(toks), self.put(msk)))
         return lps.tolist(), [int(m.sum() + (1 - m).sum()) for m in msk]
+
+    def _seqlp_fn(self, params, tokens, mask):
+        batch = {"tokens": tokens}
+        if self.cfg.frontend == "frames":
+            batch["frames"] = jnp.zeros(
+                (tokens.shape[0], self.cfg.encoder_seq, self.cfg.d_model),
+                jnp.bfloat16)
+        out = self.model.apply(params, batch, mode="train", remat=False)
+
+        def row_logprob(args):
+            # one row's [L-1, vocab] fp32 logits at a time: the whole
+            # batch's would not fit beside full-width weights
+            h, tgt = args
+            logits = self.model.logits_of(params, h)
+            logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+            return jnp.take_along_axis(logp, tgt[:, None], -1)[:, 0]
+
+        # hidden[t] predicts token[t+1]
+        lp = jax.lax.map(row_logprob, (out["hidden"][:, :-1], tokens[:, 1:]))
+        m = mask[:, 1:]
+        return jnp.sum(lp * m, 1) / jnp.maximum(jnp.sum(m, 1), 1.0)
 
     def _embed_batch(self, requests: Sequence[Request],
                      t0: Optional[float] = None) -> List[Result]:
@@ -309,9 +338,8 @@ class JaxInferenceEngine:
             return pooled
 
         fn = self._jit(("embed", B, L, tuple(sorted(extra))), embed_fn)
-        pooled = np.asarray(fn(self.params, jnp.asarray(toks),
-                               jnp.asarray(lens),
-                               {k: jnp.asarray(v) for k, v in extra.items()}))
+        pooled = np.asarray(fn(self.params, self.put(toks), self.put(lens),
+                               self.put(extra)))
         results = []
         for i, r in enumerate(requests):
             dim = int(r.metadata.get("embed_dim", 64))
@@ -366,7 +394,7 @@ class JaxInferenceEngine:
                         finish[i] = time.perf_counter()
             if done.all():
                 break
-            lg, cache = fn(self.params, cache, jnp.asarray(cur))
+            lg, cache = fn(self.params, cache, self.put(cur))
             cur = np.asarray(jnp.argmax(lg, -1), np.int32)[:, None]
         end = time.perf_counter()
         results = []
@@ -460,9 +488,10 @@ class JaxInferenceEngine:
         return d
 
     def backend_roofline(self) -> Dict[str, Any]:
-        """Roofline-derived utilization of the continuous backend's step
-        functions (prefill vs decode), from ``launch/roofline.py``; empty
-        on the static backend or before any request was served."""
+        """Roofline bound of the continuous backend's step functions
+        (prefill vs decode) against this engine's device's published
+        peaks, from ``launch/roofline.py``; empty on the static backend or
+        before any request was served."""
         if self._batcher is None:
             return {}
         return self._batcher.roofline_report()

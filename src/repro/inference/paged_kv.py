@@ -48,7 +48,8 @@ def _leaf_axis(shape, block_size: int) -> int:
 
 
 class PagedKVCache:
-    def __init__(self, model, *, block_size: int = 32, num_blocks: int = 64):
+    def __init__(self, model, *, block_size: int = 32, num_blocks: int = 64,
+                 device=None):
         if block_size < 2:
             raise ValueError("block_size must be >= 2")
         if num_blocks < 2:
@@ -61,7 +62,7 @@ class PagedKVCache:
             a = _leaf_axis(x.shape, self.block_size)
             shape = (x.shape[:a] + (self.num_blocks, self.block_size)
                      + x.shape[a + 2:])
-            return jnp.zeros(shape, x.dtype)
+            return jnp.zeros(shape, x.dtype, device=device)
 
         self._axes: Dict[str, Any] = {}
         self.pool: Dict[str, Any] = {}

@@ -33,7 +33,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
     k_start = ki * block_k
-    length = len_ref[0]
+    length = len_ref[pl.program_id(0)]
 
     @pl.when(ki == 0)
     def _init():
@@ -72,7 +72,7 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 
 def decode_attention_kernel(q, k_cache, v_cache, lengths, *,
-                            block_k: int = 512, interpret: bool = True,
+                            block_k: int = 512, interpret: bool,
                             return_lse: bool = False):
     """q: [B,H,hd]; k_cache,v_cache: [B,KV,Smax,hd]; lengths: [B].
     Returns [B,H,hd] (and optionally the per-head logsumexp [B,H,1] for
@@ -101,8 +101,9 @@ def decode_attention_kernel(q, k_cache, v_cache, lengths, *,
         kernel,
         grid=(B, KV, nk),
         in_specs=[
-            pl.BlockSpec((1,), lambda b, c, j: (b,),
-                         memory_space=pltpu.SMEM),
+            # whole [B] lengths vector in SMEM: Mosaic refuses a rank-1
+            # block narrower than the 128-lane tiling
+            pl.BlockSpec(memory_space=pltpu.SMEM),
             pl.BlockSpec((1, 1, G, hd), lambda b, c, j: (b, c, 0, 0)),
             pl.BlockSpec((1, 1, block_k, hd), lambda b, c, j: (b, c, j, 0)),
             pl.BlockSpec((1, 1, block_k, hd), lambda b, c, j: (b, c, j, 0)),

@@ -17,20 +17,6 @@ from repro.kernels.decode_attention.kernel import decode_attention_kernel
 from repro.kernels.decode_attention.ref import (decode_attention_ref,
                                                 decode_attention_with_lse_ref)
 
-# jax.shard_map only exists from 0.6; on the pinned 0.4.x it lives in
-# jax.experimental and spells the replication-check kwarg "check_rep".
-if hasattr(jax, "shard_map"):
-    def _shard_map(fn, *, mesh, in_specs, out_specs):
-        return jax.shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:
-    from jax.experimental.shard_map import shard_map as _exp_shard_map
-
-    def _shard_map(fn, *, mesh, in_specs, out_specs):
-        return _exp_shard_map(fn, mesh=mesh, in_specs=in_specs,
-                              out_specs=out_specs, check_rep=False)
-
-
 def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
@@ -122,11 +108,11 @@ def flash_decode_sharded(q, k_cache, v_cache, lengths, *, mesh, seq_axis: str,
         den = jax.lax.psum(wgt, seq_axis)
         return (num / jnp.maximum(den, 1e-30)).astype(q_.dtype)[:, None]
 
-    return _shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(dp, None, None, None), P(dp, seq_axis, None, None),
                   P(dp, seq_axis, None, None), P(dp)),
-        out_specs=P(dp, None, None, None),
+        out_specs=P(dp, None, None, None), check_vma=False,
     )(q, k_cache, v_cache, lengths)
 
 
@@ -159,7 +145,7 @@ def write_kv_sharded(cache_k, cache_v, k_new, v_new, start, *, mesh,
         new_v = jnp.where(m, vn[:, 0], cur_v)
         return ck.at[b, locc].set(new_k), cv.at[b, locc].set(new_v)
 
-    return _shard_map(
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(P(bspec, seq_axis, None, None),
                   P(bspec, seq_axis, None, None),
@@ -167,4 +153,5 @@ def write_kv_sharded(cache_k, cache_v, k_new, v_new, start, *, mesh,
                   P(bspec, None, None, None), P(bspec)),
         out_specs=(P(bspec, seq_axis, None, None),
                    P(bspec, seq_axis, None, None)),
+        check_vma=False,
     )(cache_k, cache_v, k_new, v_new, start)

@@ -86,7 +86,7 @@ def _flash_kernel(q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref, *,
 def flash_attention_kernel(q, k, v, *, causal: bool = True, window: int = 0,
                            sm_scale: float | None = None,
                            block_q: int = 128, block_k: int = 128,
-                           interpret: bool = True):
+                           interpret: bool):
     """q: [B,H,Sq,hd]; k,v: [B,KV,Skv,hd] -> [B,H,Sq,hd]."""
     B, H, Sq, hd = q.shape
     KV, Skv = k.shape[1], k.shape[2]

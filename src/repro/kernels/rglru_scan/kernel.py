@@ -44,7 +44,7 @@ def _rglru_kernel(a_ref, b_ref, h0_ref, o_ref, hT_ref, h_ref, *,
 
 
 def rglru_scan_kernel(a, b, h0, *, block_w: int = 512, block_t: int = 128,
-                      interpret: bool = True):
+                      interpret: bool):
     """a,b: [B,S,W]; h0: [B,W] -> (hs [B,S,W] fp32, hT [B,W] fp32)."""
     B, S, W = a.shape
     block_w = min(block_w, W)

@@ -53,7 +53,7 @@ def _rwkv_kernel(r_ref, k_ref, v_ref, w_ref, u_ref, s0_ref, o_ref, sT_ref,
 
 
 def rwkv6_scan_kernel(r, k, v, w, u, s0, *, block_t: int = 64,
-                      interpret: bool = True):
+                      interpret: bool):
     """r,k,v,w: [B,S,H,hd]; u: [H,hd]; s0: [B,H,hd,hd].
     Returns (o [B,S,H,hd] fp32, sT [B,H,hd,hd] fp32)."""
     B, S, H, hd = r.shape

@@ -67,7 +67,7 @@ def _sim_topk_kernel(q_ref, c_ref, vals_ref, idx_ref, sv_ref, si_ref, *,
 
 
 def similarity_topk_kernel(q, c, k: int, *, block_q: int = 128,
-                           block_n: int = 512, interpret: bool = True):
+                           block_n: int = 512, interpret: bool):
     """q: [Q, D], c: [N, D] — unit-normalized fp32 rows.
     Returns ``(vals [Q, k] fp32 descending, idx [Q, k] int32)``."""
     Q, D = q.shape

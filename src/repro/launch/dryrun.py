@@ -48,7 +48,7 @@ TRAIN_POLICY = {
     "recurrentgemma-9b": {"grad_accum": 4},
 }
 from repro.launch import roofline as rl
-from repro.launch.mesh import make_production_mesh
+from repro.launch.mesh import PRODUCTION_DEVICE_KIND, make_production_mesh
 from repro.launch.steps import build_cell
 
 
@@ -63,8 +63,8 @@ def run_cell(arch: str, shape_name: str, *, multi_pod: bool,
         lowered = cell.lower()
         compiled = lowered.compile()
         mem = compiled.memory_analysis()
-        cost = compiled.cost_analysis()
-    roof = rl.analyze(cell, lowered=lowered, compiled=compiled)
+    roof = rl.analyze(cell, lowered=lowered, compiled=compiled,
+                      device_kind=PRODUCTION_DEVICE_KIND)
     dt = time.perf_counter() - t0
     rec = roof.to_dict()
     rec.update({"ok": True, "compile_s": dt, "multi_pod": multi_pod})

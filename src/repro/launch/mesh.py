@@ -18,6 +18,9 @@ from typing import Tuple
 
 import jax
 
+# the chip the production meshes stand for (a key of roofline.PEAKS)
+PRODUCTION_DEVICE_KIND = "TPU v5 lite"
+
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)

@@ -13,8 +13,9 @@ bytes of every all-gather / all-reduce / reduce-scatter / all-to-all /
 collective-permute, applying ring-algorithm factors over the actual
 replica-group size parsed per op.
 
-Hardware constants: TPU v5e — 197 TFLOP/s bf16, 819 GB/s HBM, ~50 GB/s/link
-ICI (per the assignment).
+Hardware constants: one table of published per-chip peaks keyed by
+``jax.Device.device_kind`` (:data:`PEAKS`).  A device that is not in it
+is an error, never a default.
 """
 from __future__ import annotations
 
@@ -23,9 +24,21 @@ import json
 import re
 from typing import Any, Dict, List, Optional, Tuple
 
-PEAK_FLOPS = 197e12          # bf16 per chip
-HBM_BW = 819e9               # bytes/s per chip
-ICI_BW = 50e9                # bytes/s per link
+# Published peaks of one chip, keyed by ``device_kind``.  Source: Google
+# Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 819 GB/s HBM, and
+# 1,600 Gbit/s of chip-to-chip interconnect over four links (50 GB/s each).
+PEAKS: Dict[str, Dict[str, float]] = {
+    "TPU v5 lite": {"flops": 197e12, "hbm_bw": 819e9, "ici_bw": 50e9},
+}
+
+
+def peaks(device_kind: str) -> Dict[str, float]:
+    """Peak FLOP/s, HBM bytes/s and ICI bytes/s per link of one chip."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind "
+                         f"{device_kind!r}; known: {sorted(PEAKS)}") from None
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -114,6 +127,7 @@ class Roofline:
     shape: str
     mesh: str
     chips: int
+    device_kind: str             # key into PEAKS
     hlo_flops: float             # per device
     hlo_bytes: float             # per device
     collective_bytes: float      # per device (wire)
@@ -124,15 +138,15 @@ class Roofline:
 
     @property
     def t_compute(self) -> float:
-        return self.hlo_flops / PEAK_FLOPS
+        return self.hlo_flops / peaks(self.device_kind)["flops"]
 
     @property
     def t_memory(self) -> float:
-        return self.hlo_bytes / HBM_BW
+        return self.hlo_bytes / peaks(self.device_kind)["hbm_bw"]
 
     @property
     def t_collective(self) -> float:
-        return self.collective_bytes / ICI_BW
+        return self.collective_bytes / peaks(self.device_kind)["ici_bw"]
 
     @property
     def bottleneck(self) -> str:
@@ -155,13 +169,13 @@ class Roofline:
     def mfu_bound(self) -> float:
         """Model-FLOPs utilisation *upper bound* at the roofline: useful
         FLOPs / (chips × peak × bound-time)."""
-        denom = self.chips * PEAK_FLOPS * self.t_bound
+        denom = self.chips * peaks(self.device_kind)["flops"] * self.t_bound
         return self.model_flops / denom if denom else 0.0
 
     def to_dict(self) -> Dict[str, Any]:
         return {
             "arch": self.arch, "shape": self.shape, "mesh": self.mesh,
-            "chips": self.chips,
+            "chips": self.chips, "device_kind": self.device_kind,
             "hlo_flops_per_dev": self.hlo_flops,
             "hlo_bytes_per_dev": self.hlo_bytes,
             "collective_bytes_per_dev": self.collective_bytes,
@@ -177,7 +191,8 @@ class Roofline:
         }
 
 
-def analyze(cell, lowered=None, compiled=None) -> Roofline:
+def analyze(cell, lowered=None, compiled=None, *,
+            device_kind: str) -> Roofline:
     """Run the lower/compile (if not supplied) and extract the terms.
 
     FLOPs/bytes/collectives come from the trip-count-aware HLO walk
@@ -193,8 +208,6 @@ def analyze(cell, lowered=None, compiled=None) -> Roofline:
         compiled = lowered.compile()
     chips = cell.mesh.size
     cost = compiled.cost_analysis()
-    if isinstance(cost, list):      # older jax returns [dict]
-        cost = cost[0]
     hlo = compiled.as_text()
     hc = analyze_hlo(hlo)
     flops = hc.flops
@@ -225,12 +238,13 @@ def analyze(cell, lowered=None, compiled=None) -> Roofline:
     mesh_name = "x".join(str(s) for s in cell.mesh.devices.shape)
     return Roofline(
         arch=cell.arch, shape=cell.shape.name, mesh=mesh_name, chips=chips,
-        hlo_flops=flops, hlo_bytes=byts, collective_bytes=wire,
+        device_kind=device_kind, hlo_flops=flops, hlo_bytes=byts,
+        collective_bytes=wire,
         model_flops=cell.model_flops, collectives=counts,
         memory_per_device=mem, detail=by_path)
 
 
-def analyze_jitted(jitted, args, *, arch: str, shape: str,
+def analyze_jitted(jitted, args, *, arch: str, shape: str, device_kind: str,
                    model_flops: float = 0.0, chips: int = 1) -> Roofline:
     """Roofline terms for one jitted step function (single device).
 
@@ -245,7 +259,7 @@ def analyze_jitted(jitted, args, *, arch: str, shape: str,
     hc = analyze_hlo(compiled.as_text())
     return Roofline(
         arch=arch, shape=shape, mesh="1", chips=chips,
-        hlo_flops=hc.flops, hlo_bytes=hc.bytes,
+        device_kind=device_kind, hlo_flops=hc.flops, hlo_bytes=hc.bytes,
         collective_bytes=hc.collective_bytes, model_flops=model_flops,
         collectives=dict(hc.collective_counts))
 

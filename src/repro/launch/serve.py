@@ -4,17 +4,22 @@
         --archs proxy-8b oracle-70b --replicas 2 \
         --sql "SELECT * FROM reviews AS r WHERE AI_FILTER(...)"
 
-Stands up the Cortex-platform analogue (engines + scheduler + API service)
-on smoke-size models, loads the synthetic datasets into a catalog, and
-executes queries end-to-end with AI-aware optimization.
+Stands up the Cortex-platform analogue (engines + scheduler + API service),
+loads the synthetic datasets into a catalog, and executes queries
+end-to-end with AI-aware optimization.  The models are the CPU smoke
+presets unless ``--layers N`` asks for the published widths cut to N
+layers (``--archs proxy-8b --layers 16`` fits one v5e chip per replica;
+replica i runs on device i).
 """
 from __future__ import annotations
 
 import argparse
 
+from repro.configs import base as cfgs
 from repro.core import AisqlEngine, Catalog, CascadeConfig, ExecConfig
 from repro.data import datasets as D
 from repro.inference.api import make_engine_client
+from repro.launch import compile_cache
 from repro.tables.table import Table
 
 
@@ -37,13 +42,19 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser()
     ap.add_argument("--archs", nargs="+", default=["proxy-8b", "oracle-70b"])
     ap.add_argument("--replicas", type=int, default=1)
+    ap.add_argument("--layers", type=int, default=0,
+                    help="published widths cut to this many layers "
+                         "(0: CPU smoke presets)")
     ap.add_argument("--rows", type=int, default=48)
     ap.add_argument("--cascade", action="store_true")
     ap.add_argument("--sql", default=DEFAULT_SQL)
     ap.add_argument("--explain", action="store_true")
     args = ap.parse_args(argv)
 
-    client = make_engine_client(tuple(args.archs), replicas=args.replicas)
+    compile_cache.enable()
+    archs = [cfgs.depth_cut(a, args.layers) if args.layers else a
+             for a in args.archs]
+    client = make_engine_client(archs, replicas=args.replicas)
     engine = AisqlEngine(
         build_catalog(args.rows), client,
         executor=ExecConfig(use_cascade=args.cascade,

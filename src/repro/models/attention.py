@@ -26,7 +26,7 @@ NEG_INF = -1e30
 
 # Single-token decode attention implementation. "dense" is the pure-jnp
 # reference below; anything else routes through the kernels/decode_attention
-# flash path ("auto" = Pallas on TPU, the bitwise-equal reference off-TPU).
+# flash path ("auto" = Pallas on TPU, the jnp reference off-TPU).
 # Trace-time state: the continuous-batching backend arms it around its
 # jitted decode step, so the choice is baked into each compiled step fn.
 _DECODE_IMPL = "dense"

@@ -132,13 +132,23 @@ def test_parity_midstream_admission(static_engine, cont_engine):
 
 
 def test_parity_chunked_prefill_long_prompt(static_engine, cont_engine):
-    # prompts several chunks long: chunked decode-mode prefill must equal
-    # the static one-shot prefill bitwise
+    # prompts several chunks long: chunked decode-mode prefill must match
+    # the static one-shot prefill.  Text, token counts and credits are
+    # exact.  SCOREs get a tolerance: the two prefills reduce attention in
+    # a different order, so the float32 logits can differ in their last
+    # bits; 1e-6 is about 16 float32 ulps of a score near 0.5
     long = "the quick brown fox jumps over the lazy dog " * 4
     reqs = [Request(long + f"[{i}]", "proxy-8b",
                     SCORE if i % 2 else COMPLETE, max_tokens=6,
                     request_id=i + 1) for i in range(4)]
-    assert _serve(static_engine, reqs) == _serve(cont_engine, reqs)
+    static, cont = _serve(static_engine, reqs), _serve(cont_engine, reqs)
+    no_score = lambda row: row[:3] + row[4:]
+    assert [no_score(r) for r in static] == [no_score(r) for r in cont]
+    for s, c in zip(static, cont):
+        if s[1] == SCORE:
+            assert c[3] == pytest.approx(s[3], rel=0, abs=1e-6)
+        else:
+            assert s[3] is None and c[3] is None
 
 
 def test_parity_repeated_waves_reuse_pool(static_engine, cont_engine):
@@ -290,11 +300,9 @@ def test_backend_stats_and_roofline(cont_engine):
     assert stats["backend"] == "continuous"
     assert stats["prefill_steps"] > 0 and stats["decode_steps"] > 0
     assert stats["kv_peak_blocks"] > 0
-    rep = cont_engine.backend_roofline()
-    assert set(rep) == {"prefill", "decode"}
-    for kind in rep.values():
-        assert kind["tokens_per_step"] > 0
-        assert 0.0 <= kind["mfu_bound"] <= 1.0
+    # the CPU has no published peaks: no bound is divided out for it
+    with pytest.raises(ValueError, match="no published peaks"):
+        cont_engine.backend_roofline()
 
     # the static backend reports too, without batcher telemetry
     st = JaxInferenceEngine("proxy-8b", smoke=True, backend="static")
