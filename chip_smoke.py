@@ -204,11 +204,24 @@ def serve_queries(scheduler, model: str):
 
 
 def check_decode_step_has_kernel(engine) -> None:
-    batcher = engine._batcher
-    keys = [k for k in batcher._step_specs if k[0] == "cb_decode"]
+    """Compile a decode step the served queries ran, from its key's
+    shapes, and look for the Pallas call in it."""
+    import jax
+    import jax.numpy as jnp
+    keys = [k for k in engine._jit_cache if k[0] == "cb_decode"]
     _check(bool(keys), "the served queries ran decode steps")
-    _, _, sds = batcher._step_specs[keys[0]]
-    hlo = engine._jit_cache[keys[0]].lower(*sds).compile().as_text()
+    _, slots, nb, _ = keys[0]
+
+    def like(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32)
+
+    args = (jax.tree.map(like, engine.params),
+            jax.tree.map(like, engine._batcher.kv.pool),
+            ints(slots, nb), ints(slots), ints(slots), ints(slots, 1))
+    hlo = engine._jit_cache[keys[0]].lower(*args).compile().as_text()
     _check("tpu_custom_call" in hlo,
            "the compiled decode step contains the Pallas tpu_custom_call")
 

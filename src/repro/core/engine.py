@@ -27,7 +27,7 @@ from repro.core.executor import ExecConfig, Executor
 from repro.core.optimizer import Optimizer, OptimizerConfig
 from repro.core.stats import StatsStore
 from repro.inference.api import CortexClient
-from repro.obs.trace import NOOP, activate, critical_path
+from repro.obs.trace import NOOP, activate, critical_path, lock_wait_s
 from repro.tables.table import Table
 
 
@@ -97,6 +97,10 @@ class QueryReport:
     # was built with a tracing-enabled Observability — see docs/
     # observability.md for the span taxonomy and export formats
     trace: Optional[Dict[str, Any]] = None
+    # seconds this query's thread spent blocked on the request
+    # pipeline's and the scheduler's dispatch locks, i.e. queued behind
+    # other queries' engine batches
+    lock_wait_s: float = 0.0
 
     def explain_analyze(self) -> str:
         """EXPLAIN ANALYZE-style rendering: the optimized plan followed
@@ -359,6 +363,7 @@ class AisqlEngine:
         the returned table and all telemetry are unchanged."""
         obs = self.obs
         tr = obs.tracer() if obs is not None and obs.enabled else NOOP
+        waited0 = lock_wait_s()
         before = self.client.snapshot()
         t0 = time.perf_counter()
         with activate(tr), tr.span("query", kind="query") as qsp:
@@ -419,7 +424,8 @@ class AisqlEngine:
             partitions=self.exec.partition_telemetry,
             semindex=self.exec.index_telemetry,
             memo=memo_info,
-            trace=tr.to_dict() if tr.enabled else None)
+            trace=tr.to_dict() if tr.enabled else None,
+            lock_wait_s=lock_wait_s() - waited0)
         if self.stats_path is not None:
             self.stats.save(self.stats_path)
         if self.semindex_path is not None and self.semindex is not None:

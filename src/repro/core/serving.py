@@ -55,6 +55,7 @@ from repro.inference.pipeline import PipelineConfig, RequestPipeline
 from repro.inference.scheduler import Scheduler
 from repro.obs import Observability
 from repro.obs.metrics import MetricsRegistry, _HistChild
+from repro.obs.trace import lock_wait_s
 from repro.tables.table import Table
 
 
@@ -834,6 +835,7 @@ class ServingEngine:
                 self._queue.put(ticket)
                 return True
             ticket.queue_wait_s = time.perf_counter() - ticket.submitted_at
+            waited0 = lock_wait_s()
             session = self._checkout(ticket.tenant)
             try:
                 t0 = time.perf_counter()
@@ -841,6 +843,10 @@ class ServingEngine:
                             if ticket._batchq is not None else None)
                 table, report = session.run(ticket.sql, on_batch=on_batch)
                 ticket.wall_s = time.perf_counter() - t0
+                if report is not None:
+                    # a new session registers its meter under the
+                    # pipeline's dispatch lock: its wait is the query's
+                    report.lock_wait_s = lock_wait_s() - waited0
                 ticket.report = report
                 ticket._table = table
                 if report is not None and report.trace is not None:

@@ -32,15 +32,24 @@ bitwise equal to the dense cache attention.  The parity tests in
 ``tests/test_backend.py`` pin these.  On the TPU, bfloat16 activations
 and shape-dependent MXU sums move SCOREs by about 1e-2 even within the
 static path; ``chip_smoke.py`` holds the chip to stated tolerances.
+
+Tracing: the loop's phases are spans (``engine.wave``, ``engine.tokenize``,
+``engine.admit``, ``engine.prefill_step``, ``engine.decode_step``,
+``engine.readback``, ``engine.retire``; see ``repro.obs.trace``), so a
+``jax.profiler`` trace names what the host did in each device idle gap.
+The step spans time the host side only, building inputs and dispatching;
+the device's time is the profiler's.  ``stats()`` adds the wall seconds
+inside ``serve`` (``loop_s``) and those blocked in readbacks
+(``readback_s``).  Nothing here synchronizes with the device beyond the
+readbacks the loop needs anyway.
 """
 from __future__ import annotations
 
 import dataclasses
 import time
 from collections import deque
-from typing import Any, Deque, Dict, List, Optional, Sequence, Tuple
+from typing import Any, Deque, Dict, List, Optional, Sequence
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -50,6 +59,7 @@ from repro.inference.backend import (COMPLETE, SCORE, EngineFailure, Request,
                                      Result, credits_for)
 from repro.inference.paged_kv import PagedKVCache
 from repro.models import attention
+from repro.obs.trace import active_tracer
 
 
 def supports(cfg) -> bool:
@@ -121,11 +131,12 @@ class ContinuousBatcher:
         self.retired_eos = 0       # retired on EOS before max_tokens
         self.prefill_steps = 0
         self.decode_steps = 0
+        self.prefill_rows = 0      # prefilling slots, summed over prefill steps
         self.prefill_tokens = 0    # prompt tokens written via chunked prefill
         self.decode_tokens = 0     # decode-step slot participations
         self.peak_blocks = 0
-        # roofline: abstract args of each step key, for AOT lower/compile
-        self._step_specs: Dict[Any, Tuple[str, int, Tuple[Any, ...]]] = {}
+        self.loop_s = 0.0          # wall seconds inside serve()
+        self.readback_s = 0.0      # of which blocked reading step outputs
 
     # ------------------------------------------------------------------
     # serve loop
@@ -135,21 +146,42 @@ class ContinuousBatcher:
               t0: Optional[float] = None) -> List[Result]:
         """Serve SCORE/COMPLETE requests to completion; returns results in
         submission order with per-request completion-time latency."""
-        t0 = time.perf_counter() if t0 is None else t0
+        start = time.perf_counter()
+        t0 = start if t0 is None else t0
+        tr = active_tracer()
         self.waves += 1
+        try:
+            with tr.span("engine.wave", kind="engine.wave",
+                         requests=len(requests)):
+                return self._serve(requests, t0, tr)
+        finally:
+            self.loop_s += time.perf_counter() - start
+
+    def _serve(self, requests, t0, tr) -> List[Result]:
         pending: Deque[_Seq] = deque()
-        for i, r in enumerate(requests):
-            enc = tok.encode(r.prompt, max_len=self.engine.max_seq)
-            pending.append(_Seq(req=r, index=i, enc=enc, slot=-1, blocks=[]))
+        with tr.span("engine.tokenize", kind="engine.tokenize"):
+            for i, r in enumerate(requests):
+                enc = tok.encode(r.prompt, max_len=self.engine.max_seq)
+                pending.append(_Seq(req=r, index=i, enc=enc, slot=-1,
+                                    blocks=[]))
         active: List[Optional[_Seq]] = [None] * self.slots
         results: List[Optional[Result]] = [None] * len(requests)
         while pending or any(s is not None for s in active):
-            self._admit(pending, active)
+            with tr.span("engine.admit", kind="engine.admit"):
+                self._admit(pending, active)
             if any(s is not None and s.state == "prefill" for s in active):
                 self._prefill_step(active, results, t0)
             if any(s is not None and s.state == "decode" for s in active):
                 self._decode_step(active, results, t0)
         return results  # type: ignore[return-value]
+
+    def _readback(self, x, dtype) -> np.ndarray:
+        """A step output on the host: blocks until the device computed it."""
+        start = time.perf_counter()
+        with active_tracer().span("engine.readback", kind="engine.readback"):
+            out = np.asarray(x, dtype)
+        self.readback_s += time.perf_counter() - start
+        return out
 
     # ------------------------------------------------------------------
 
@@ -240,33 +272,35 @@ class ContinuousBatcher:
     def _prefill_step(self, active, results, t0) -> None:
         C = self.prefill_chunk
         B = self.slots
-        nb = self._gather_width(active, C)
-        toks = np.zeros((B, C), np.int32)
-        counts = np.zeros((B,), np.int32)
         pre = [s for s in active if s is not None and s.state == "prefill"]
-        for s in pre:
-            v = min(C, len(s.enc) - s.filled)
-            toks[s.slot, :v] = s.enc[s.filled:s.filled + v]
-            counts[s.slot] = v
-        key = ("cb_prefill", B, C, nb, self.decode_impl)
-        fn = self.engine._jit(key, self._prefill_fn, donate=(1,))
-        dev = self._device_state(active, nb)
-        args = (self.engine.params, self.kv.pool, dev["tables"], dev["lens"],
+        with active_tracer().span("engine.prefill_step",
+                                  kind="engine.prefill_step", rows=len(pre)):
+            nb = self._gather_width(active, C)
+            toks = np.zeros((B, C), np.int32)
+            counts = np.zeros((B,), np.int32)
+            for s in pre:
+                v = min(C, len(s.enc) - s.filled)
+                toks[s.slot, :v] = s.enc[s.filled:s.filled + v]
+                counts[s.slot] = v
+            key = ("cb_prefill", B, C, nb, self.decode_impl)
+            fn = self.engine._jit(key, self._prefill_fn, donate=(1,))
+            dev = self._device_state(active, nb)
+            self.kv.pool, logits, new_lens = fn(
+                self.engine.params, self.kv.pool, dev["tables"], dev["lens"],
                 self.engine.put(counts), self.engine.put(toks))
-        self._record_spec(key, "prefill", B * C, args)
-        self.kv.pool, logits, new_lens = fn(*args)
-        self.prefill_steps += 1
-        self.prefill_tokens += int(counts.sum())
-        for s in pre:
-            v = int(counts[s.slot])
-            s.filled += v
-            self.lens_np[s.slot] += v
-        dev["lens"] = new_lens
+            self.prefill_steps += 1
+            self.prefill_rows += len(pre)
+            self.prefill_tokens += int(counts.sum())
+            for s in pre:
+                v = int(counts[s.slot])
+                s.filled += v
+                self.lens_np[s.slot] += v
+            dev["lens"] = new_lens
         lf = None
         for s in pre:
             if s.filled >= len(s.enc):
                 if lf is None:
-                    lf = np.asarray(logits, np.float32)
+                    lf = self._readback(logits, np.float32)
                 self._finish_prefill(s, lf[s.slot], active, results, t0)
 
     def _prefill_fn(self, params, pool, tables, lens, counts, toks):
@@ -302,24 +336,25 @@ class ContinuousBatcher:
 
     def _decode_step(self, active, results, t0) -> None:
         B = self.slots
-        nb = self._gather_width(active, 1)
-        cur = np.zeros((B, 1), np.int32)
         dec = [s for s in active if s is not None and s.state == "decode"]
-        for s in dec:
-            cur[s.slot, 0] = s.cur
-        key = ("cb_decode", B, nb, self.decode_impl)
-        fn = self.engine._jit(key, self._decode_fn, donate=(1,))
-        dev = self._device_state(active, nb)
-        args = (self.engine.params, self.kv.pool, dev["tables"], dev["lens"],
+        with active_tracer().span("engine.decode_step",
+                                  kind="engine.decode_step", rows=len(dec)):
+            nb = self._gather_width(active, 1)
+            cur = np.zeros((B, 1), np.int32)
+            for s in dec:
+                cur[s.slot, 0] = s.cur
+            key = ("cb_decode", B, nb, self.decode_impl)
+            fn = self.engine._jit(key, self._decode_fn, donate=(1,))
+            dev = self._device_state(active, nb)
+            self.kv.pool, nxt_dev, new_lens = fn(
+                self.engine.params, self.kv.pool, dev["tables"], dev["lens"],
                 dev["act"], self.engine.put(cur))
-        self._record_spec(key, "decode", B, args)
-        self.kv.pool, nxt_dev, new_lens = fn(*args)
-        self.decode_steps += 1
-        self.decode_tokens += len(dec)
-        nxt = np.asarray(nxt_dev, np.int32)
-        for s in dec:
-            self.lens_np[s.slot] += 1
-        dev["lens"] = new_lens
+            self.decode_steps += 1
+            self.decode_tokens += len(dec)
+            for s in dec:
+                self.lens_np[s.slot] += 1
+            dev["lens"] = new_lens
+        nxt = self._readback(nxt_dev, np.int32)
         for s in dec:
             s.cur = int(nxt[s.slot])
             self._consume(s, active, results, t0)
@@ -346,30 +381,31 @@ class ContinuousBatcher:
 
     def _retire(self, s: _Seq, active, results, t0,
                 score: Optional[float] = None) -> None:
-        r = s.req
-        eng = self.engine
-        ti = len(s.enc)
-        if r.kind == SCORE:
-            res = Result(r.request_id, eng.arch, SCORE, score=score,
-                         tokens_in=ti, credits=credits_for(eng.arch, ti),
-                         engine_id=eng.engine_id)
-        else:
-            res = Result(r.request_id, eng.arch, COMPLETE,
-                         text=tok.decode(s.out), tokens_in=ti,
-                         tokens_out=len(s.out),
-                         credits=credits_for(eng.arch, ti + len(s.out)),
-                         engine_id=eng.engine_id)
-        res.latency_s = time.perf_counter() - t0
-        results[s.index] = res
-        self.kv.free_blocks(s.blocks)
-        active[s.slot] = None
-        self.lens_np[s.slot] = 0
-        self.tables_np[s.slot, :] = 0
-        self._dev = None
-        self.retired += 1
+        with active_tracer().span("engine.retire", kind="engine.retire"):
+            r = s.req
+            eng = self.engine
+            ti = len(s.enc)
+            if r.kind == SCORE:
+                res = Result(r.request_id, eng.arch, SCORE, score=score,
+                             tokens_in=ti, credits=credits_for(eng.arch, ti),
+                             engine_id=eng.engine_id)
+            else:
+                res = Result(r.request_id, eng.arch, COMPLETE,
+                             text=tok.decode(s.out), tokens_in=ti,
+                             tokens_out=len(s.out),
+                             credits=credits_for(eng.arch, ti + len(s.out)),
+                             engine_id=eng.engine_id)
+            res.latency_s = time.perf_counter() - t0
+            results[s.index] = res
+            self.kv.free_blocks(s.blocks)
+            active[s.slot] = None
+            self.lens_np[s.slot] = 0
+            self.tables_np[s.slot, :] = 0
+            self._dev = None
+            self.retired += 1
 
     # ------------------------------------------------------------------
-    # telemetry / roofline
+    # telemetry
     # ------------------------------------------------------------------
 
     def stats(self) -> Dict[str, Any]:
@@ -380,43 +416,13 @@ class ContinuousBatcher:
             "retired": self.retired, "retired_eos": self.retired_eos,
             "prefill_steps": self.prefill_steps,
             "decode_steps": self.decode_steps,
+            "prefill_rows": self.prefill_rows,
             "prefill_tokens": self.prefill_tokens,
             "decode_tokens": self.decode_tokens,
             "decode_slot_occupancy": occ,
             "kv_blocks": self.kv.num_blocks,
             "kv_block_size": self.block_size,
             "kv_peak_blocks": self.peak_blocks,
+            "loop_s": self.loop_s,
+            "readback_s": self.readback_s,
         }
-
-    def _record_spec(self, key, kind: str, tokens_per_step: int, args
-                     ) -> None:
-        if key not in self._step_specs:
-            sds = jax.tree.map(
-                lambda x: jax.ShapeDtypeStruct(jnp.shape(x),
-                                               jnp.result_type(x)), args)
-            self._step_specs[key] = (kind, tokens_per_step, sds)
-
-    def roofline_report(self) -> Dict[str, Any]:
-        """Roofline bound per step kind (prefill vs decode), from
-        AOT-compiling the largest-shape step function of each kind
-        (``launch/roofline.py`` does the HLO walk) against the published
-        peaks of the engine's device (a device with none raises
-        ValueError).  A bound computed from the compiled program, not a
-        measurement."""
-        from repro.launch import roofline
-        device_kind = self.engine.device.device_kind
-        n_params = sum(int(x.size) for x in jax.tree.leaves(self.engine.params))
-        best: Dict[str, Tuple[Any, int, Tuple[Any, ...]]] = {}
-        for key, (kind, tps, sds) in self._step_specs.items():
-            if kind not in best or tps >= best[kind][1]:
-                best[kind] = (key, tps, sds)
-        out: Dict[str, Any] = {}
-        for kind, (key, tps, sds) in best.items():
-            fn = self.engine._jit_cache[key]
-            r = roofline.analyze_jitted(
-                fn, sds, arch=self.engine.arch,
-                shape=f"{kind}-step B={self.slots}",
-                device_kind=device_kind,
-                model_flops=2.0 * n_params * tps)
-            out[kind] = {"tokens_per_step": tps, **r.to_dict()}
-        return out

@@ -23,7 +23,11 @@ This is the Cortex Platform "Inference Engine" (paper §2) adapted to TPU:
     token on the embedding tier);
   * per-request credit metering (AI credits, §4) and latency accounting;
   * fault injection (EngineFailure) so the scheduler's retry/straggler
-    logic is testable.
+    logic is testable;
+  * spans on the profiler's clock (``repro.obs.trace``): one per static
+    batch (``engine.score`` / ``engine.classify`` / ``engine.complete`` /
+    ``engine.embed``) and ``engine.first_call`` around the first
+    execution of each jitted program key, so a compile names its step.
 
 Modality frontends are stubs per the assignment: FILE inputs are mapped to
 deterministic pseudo-embeddings derived from the URI hash.
@@ -45,6 +49,13 @@ from repro.inference.backend import (CLASSIFY, COMPLETE, EMBED, SCORE,
                                      EngineFailure, Request, Result,
                                      credits_for)
 from repro.models import model_zoo
+from repro.obs.trace import active_tracer
+
+# Program names of the continuous batcher's jitted steps, pinned: HLO
+# modules and the profiler's events read ``jit__prefill_fn`` and
+# ``jit__decode_fn`` however the step functions are written, and device
+# trace readers match these names.
+_PROGRAM_NAMES = {"cb_prefill": "_prefill_fn", "cb_decode": "_decode_fn"}
 
 
 def _bucket(n: int, lo: int = 32) -> int:
@@ -52,6 +63,31 @@ def _bucket(n: int, lo: int = 32) -> int:
     while b < n:
         b *= 2
     return b
+
+
+def _named(fn, name: str):
+    """``fn`` under the function name ``name`` (jit's program name)."""
+    def program(*args):
+        return fn(*args)
+    program.__name__ = program.__qualname__ = name
+    return program
+
+
+def _first_call(key, jitted):
+    """``jitted`` whose first call, the one that traces and compiles (or
+    loads from the compile cache), is an ``engine.first_call`` span that
+    carries ``key``."""
+    called = False
+
+    def call(*args):
+        nonlocal called
+        if called:
+            return jitted(*args)
+        called = True
+        with active_tracer().span("engine.first_call",
+                                  kind="engine.first_call", key=repr(key)):
+            return jitted(*args)
+    return call
 
 
 def _hash_embed(key: str, shape, scale=0.1) -> np.ndarray:
@@ -164,10 +200,15 @@ class JaxInferenceEngine:
         return jax.device_put(x, self.device)
 
     def _jit(self, key, fn, donate=()):
-        if key not in self._jit_cache:
-            self.jit_compiles += 1
-            self._jit_cache[key] = jax.jit(fn, donate_argnums=donate)
-        return self._jit_cache[key]
+        jitted = self._jit_cache.get(key)
+        if jitted is not None:
+            return jitted
+        self.jit_compiles += 1
+        name = _PROGRAM_NAMES.get(key[0])
+        if name is not None:
+            fn = _named(fn, name)
+        jitted = self._jit_cache[key] = jax.jit(fn, donate_argnums=donate)
+        return _first_call(key, jitted)
 
     def _prefill(self, requests: Sequence[Request], cap: Optional[int] = None,
                  extra_capacity: int = 0):
@@ -429,17 +470,20 @@ class JaxInferenceEngine:
                 by_kind.setdefault(r.kind, []).append(r)
         if cont:
             out.extend(self._batcher.serve(cont, t0))
+        tr = active_tracer()
         for kind, reqs in by_kind.items():
             for i in range(0, len(reqs), self.max_batch):
                 chunk = reqs[i:i + self.max_batch]
-                if kind == SCORE:
-                    out.extend(self._score_batch(chunk, t0))
-                elif kind == CLASSIFY:
-                    out.extend(self._classify_batch(chunk, t0))
-                elif kind == EMBED:
-                    out.extend(self._embed_batch(chunk, t0))
-                else:
-                    out.extend(self._complete_batch(chunk, t0))
+                with tr.span(f"engine.{kind}", kind=f"engine.{kind}",
+                             requests=len(chunk)):
+                    if kind == SCORE:
+                        out.extend(self._score_batch(chunk, t0))
+                    elif kind == CLASSIFY:
+                        out.extend(self._classify_batch(chunk, t0))
+                    elif kind == EMBED:
+                        out.extend(self._embed_batch(chunk, t0))
+                    else:
+                        out.extend(self._complete_batch(chunk, t0))
         for r in out:
             self.total_credits += r.credits
             self.total_tokens += r.tokens_in + r.tokens_out
@@ -486,15 +530,6 @@ class JaxInferenceEngine:
         if self._batcher is not None:
             d.update(self._batcher.stats())
         return d
-
-    def backend_roofline(self) -> Dict[str, Any]:
-        """Roofline bound of the continuous backend's step functions
-        (prefill vs decode) against this engine's device's published
-        peaks, from ``launch/roofline.py``; empty on the static backend or
-        before any request was served."""
-        if self._batcher is None:
-            return {}
-        return self._batcher.roofline_report()
 
 
 def _stamp_latency(results: List[Result], t0: float) -> List[Result]:

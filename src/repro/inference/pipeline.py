@@ -50,19 +50,23 @@ futures can never attach to an item mid-resolution, and a ``result()``
 call racing a dispatch simply blocks on the lock until its future is
 resolved.  Concurrency wins come from coalescing and caching *across*
 the querying threads, not from parallel dispatch; the backends model
-batch-parallel execution internally.
+batch-parallel execution internally.  A thread that finds the lock held
+waits as a ``pipeline.lock_wait`` span; ``PipelineStats.lock_wait_s``
+and ``lock_waits`` count those waits, and the waiting query's
+``QueryReport.lock_wait_s`` carries its share.
 """
 from __future__ import annotations
 
 import dataclasses
 import threading
 import time
+from contextlib import contextmanager
 from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 from repro.inference.backend import EngineFailure, Request, Result
 from repro.inference.scheduler import Scheduler, SchedulerError
 from repro.obs.metrics import locked_snapshot
-from repro.obs.trace import active_tracer
+from repro.obs.trace import acquire_timed, active_tracer
 
 
 class RequestFailed(RuntimeError):
@@ -182,6 +186,8 @@ class PipelineStats:
     retries: int = 0              # batch re-dispatches after a fault
     failures: int = 0             # requests that exhausted their retries
     queue_wait_s: float = 0.0     # sum over dispatched reqs of queue time
+    lock_waits: int = 0           # entries that blocked on the dispatch lock
+    lock_wait_s: float = 0.0      # seconds those entries waited for it
     batch_size_hist: Dict[int, int] = dataclasses.field(default_factory=dict)
     # submissions per request kind (score/classify/complete): lets the
     # stats store / docs attribute dedup wins to operator families
@@ -275,8 +281,20 @@ class RequestPipeline:
         """Bill ``owner``'s dispatched requests through ``fn`` instead of
         the default ``on_dispatch`` hook (exactly one of the two sees
         each dispatched result — spend is conserved)."""
-        with self._lock:
+        with self._dispatch_lock():
             self._meters[owner] = fn
+
+    @contextmanager
+    def _dispatch_lock(self):
+        """Hold ``self._lock``; a wait for it is timed and counted."""
+        waited = acquire_timed(self._lock, "pipeline.lock_wait")
+        try:
+            if waited:
+                self.stats.lock_waits += 1
+                self.stats.lock_wait_s += waited
+            yield
+        finally:
+            self._lock.release()
 
     # ------------------------------------------------------------------
     # submission
@@ -288,7 +306,7 @@ class RequestPipeline:
 
     def submit_many(self, requests: Sequence[Request], *,
                     owner: Optional[str] = None) -> List[ResultFuture]:
-        with self._lock:
+        with self._dispatch_lock():
             return self._submit_many_locked(requests, owner)
 
     def _submit_many_locked(self, requests: Sequence[Request],
@@ -359,7 +377,7 @@ class RequestPipeline:
         or — with ``owner=`` — only the items a given owner submitted
         (the serving engine's per-session barrier: other sessions' work
         stays queued and keeps coalescing)."""
-        with self._lock:
+        with self._dispatch_lock():
             models = [model] if model is not None else list(self._queues)
             flushed_any = False
             for m in models:
@@ -403,7 +421,7 @@ class RequestPipeline:
         moves to a surviving owner — a session is never billed for a
         dispatch that only served other sessions.
         """
-        with self._lock:
+        with self._dispatch_lock():
             want = {id(f) for f in futures}
             cancelled = self._cancel_items_locked(
                 lambda item: item.futures and all(
@@ -430,7 +448,7 @@ class RequestPipeline:
         dedup-attached to stay queued (that owner still awaits them),
         but the billing tag moves to a surviving owner so the eventual
         dispatch is never charged to the failed query."""
-        with self._lock:
+        with self._dispatch_lock():
             cancelled = self._cancel_items_locked(
                 lambda item: item.owners == {owner})
             # items other owners still await: drop the failed owner from

@@ -31,7 +31,7 @@ from typing import Dict, List, Optional, Sequence
 from repro.inference.backend import (EngineFailure, EngineTimeout,
                                      InferenceBackend, Request, Result)
 from repro.obs.metrics import locked_snapshot
-from repro.obs.trace import active_tracer
+from repro.obs.trace import acquire_timed, active_tracer
 
 _DEFAULT_CAPACITY = 32
 
@@ -166,9 +166,13 @@ class Scheduler:
 
     def submit(self, requests: Sequence[Request]) -> List[Result]:
         """Route a mixed-model batch; preserves input order.  Thread-safe
-        (serialized on the scheduler lock)."""
-        with self._lock:
+        (serialized on the scheduler lock; a wait for it is a
+        ``scheduler.lock_wait`` span)."""
+        acquire_timed(self._lock, "scheduler.lock_wait")
+        try:
             return self._submit_locked(requests)
+        finally:
+            self._lock.release()
 
     def _submit_locked(self, requests: Sequence[Request]) -> List[Result]:
         self.submits += 1

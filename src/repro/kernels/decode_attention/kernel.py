@@ -116,6 +116,7 @@ def decode_attention_kernel(q, k_cache, v_cache, lengths, *,
             pltpu.VMEM((G, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_decode",
     )(lengths, qg, k_cache, v_cache)
     out = res[0].reshape(B, H, hd)
     if return_lse:

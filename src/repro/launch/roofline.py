@@ -244,26 +244,6 @@ def analyze(cell, lowered=None, compiled=None, *,
         memory_per_device=mem, detail=by_path)
 
 
-def analyze_jitted(jitted, args, *, arch: str, shape: str, device_kind: str,
-                   model_flops: float = 0.0, chips: int = 1) -> Roofline:
-    """Roofline terms for one jitted step function (single device).
-
-    ``args`` are ShapeDtypeStructs (or arrays) matching the call signature
-    — the function is AOT lowered/compiled and its post-optimization HLO
-    walked with trip counts (``hlo_cost``), exactly as :func:`analyze`
-    does for dry-run cells.  Used by the serving backend to report
-    utilization per decode/prefill step mix.
-    """
-    from repro.launch.hlo_cost import analyze_hlo
-    compiled = jitted.lower(*args).compile()
-    hc = analyze_hlo(compiled.as_text())
-    return Roofline(
-        arch=arch, shape=shape, mesh="1", chips=chips,
-        device_kind=device_kind, hlo_flops=hc.flops, hlo_bytes=hc.bytes,
-        collective_bytes=hc.collective_bytes, model_flops=model_flops,
-        collectives=dict(hc.collective_counts))
-
-
 def fmt_row(r: Roofline) -> str:
     return (f"{r.arch:22s} {r.shape:12s} {r.mesh:9s} "
             f"C={r.t_compute*1e3:9.2f}ms M={r.t_memory*1e3:9.2f}ms "

@@ -8,9 +8,11 @@ One :class:`Observability` object bundles what a serving process needs:
 - a :class:`MetricsRegistry` of labeled counter/gauge/histogram
   families, exposed at ``/v1/metrics`` in Prometheus text format.
 
-``enabled`` gates *tracing* only — metrics are always recorded once an
-Observability object is attached, because they are cheap (a dict lookup
-and a locked increment) while span trees allocate per call site.
+``enabled`` gates the span *trees* only.  Metrics are always recorded
+once an Observability object is attached, because they are cheap (a dict
+lookup and a locked increment) while span trees allocate per call site;
+and every span is a ``jax.profiler`` annotation either way, recorded
+whenever a profiler trace is running (``docs/observability.md``).
 """
 from .metrics import (BUCKET_BOUNDS, BUCKET_COUNT, BUCKET_FACTOR,
                       BUCKET_START, METRIC_FAMILIES, QUANTILE_REL_ERROR,
@@ -32,6 +34,9 @@ __all__ = [
 
 class Observability:
     """Tracing + metrics for one engine or serving process.
+
+    ``enabled=False`` records no span trees; the spans still reach a
+    running ``jax.profiler`` trace as annotations.
 
     ``clock`` is a *factory* of clock callables — pass ``TickClock`` to
     give every query tracer a fresh deterministic clock (byte-stable

@@ -9,9 +9,14 @@ production (``time.perf_counter``).
 
 The tracer is activated per query on the executing thread via the
 ``activate`` context manager; deep call sites (pipeline, scheduler,
-spill manager) fetch it with ``active_tracer()`` — which returns the
-shared no-op tracer when tracing is off, so the disabled path costs a
-thread-local read and an attribute check.
+engine step loop, spill manager) fetch it with ``active_tracer()`` —
+which returns the shared no-op tracer when tracing is off.
+
+Every span, recorded or not, also enters ``annotation(name)``: a
+``jax.profiler.TraceAnnotation``, so a ``jax.profiler`` trace shows the
+program's spans on its host planes, on the device trace's clock, whether
+or not tracing is on.  With no profiler recording an annotation is inert
+(under a microsecond), and the disabled path builds no span objects.
 """
 from __future__ import annotations
 
@@ -19,6 +24,8 @@ import json
 import threading
 import time
 from contextlib import contextmanager
+
+from jax.profiler import TraceAnnotation
 
 # ---------------------------------------------------------------------------
 # Taxonomy — the single source of truth the docs (and test_docs) check
@@ -34,7 +41,21 @@ SPAN_KINDS = {
     "predicate": "one AI predicate evaluated over a row batch",
     "cascade": "proxy/oracle cascade run for one predicate batch",
     "pipeline.dispatch": "one coalesced batch leaving the request pipeline",
+    "pipeline.lock_wait": "a submit or flush blocked on the pipeline's dispatch lock",
+    "scheduler.lock_wait": "a submit blocked on the scheduler's dispatch lock",
     "dispatch.replica": "one batch attempt on one backend replica",
+    "engine.wave": "one continuous-batching serve call, admission to last retirement",
+    "engine.tokenize": "encoding a wave's prompts",
+    "engine.admit": "admitting queued sequences into free slots",
+    "engine.prefill_step": "host side of a chunked-prefill step: inputs and dispatch",
+    "engine.decode_step": "host side of a decode step: inputs and dispatch",
+    "engine.readback": "blocked on a step's logits or next tokens",
+    "engine.retire": "retiring one finished sequence",
+    "engine.first_call": "first execution of a jitted program key (traces, compiles)",
+    "engine.score": "one static-path SCORE batch",
+    "engine.classify": "one AI_CLASSIFY batch: label log-probabilities, static path",
+    "engine.complete": "one static-path COMPLETE batch",
+    "engine.embed": "one EMBED batch",
 }
 
 EVENT_KINDS = {
@@ -105,36 +126,29 @@ class Span:
         }
 
 
-class _NoopSpan:
+class _Annotation(TraceAnnotation):
+    """A profiler annotation; as the no-op tracer's span it takes and
+    drops attributes."""
     __slots__ = ()
 
     def set(self, **attrs):
         return self
 
 
-_NOOP_SPAN = _NoopSpan()
-
-
-class _NoopCtx:
-    """Reusable context manager yielding the shared no-op span."""
-    __slots__ = ()
-
-    def __enter__(self):
-        return _NOOP_SPAN
-
-    def __exit__(self, *exc):
-        return False
-
-
-_NOOP_CTX = _NoopCtx()
+def annotation(name: str, **attrs) -> _Annotation:
+    """The profiler annotation every span enters, traced or not: ``name``
+    (and ``attrs`` as its metadata) on the host planes of a running
+    ``jax.profiler`` trace, nothing otherwise."""
+    return _Annotation(name, **attrs)
 
 
 class _NoopTracer:
-    """Shared disabled tracer: every operation is a constant-time no-op."""
+    """Shared disabled tracer: records nothing; its spans are profiler
+    annotations only."""
     enabled = False
 
     def span(self, name, kind="span", **attrs):
-        return _NOOP_CTX
+        return annotation(name, **attrs)
 
     def event(self, name, **attrs):
         pass
@@ -186,7 +200,8 @@ class Tracer:
             sp.attrs.update(attrs)
         self._stack.append(sp)
         try:
-            yield sp
+            with annotation(name, **attrs):
+                yield sp
         finally:
             self._stack.pop()
             sp.t1 = self.now()
@@ -294,6 +309,27 @@ def activate(tracer):
         yield tracer
     finally:
         _tls.tracer = prev
+
+
+def acquire_timed(lock, kind: str) -> float:
+    """Acquire ``lock`` and return the seconds this thread waited for it.
+
+    A non-blocking try comes first, so an uncontended acquisition reads
+    no clock and opens no span.  A wait is a ``kind`` span and adds to
+    this thread's `lock_wait_s` (a query's thread reads the difference)."""
+    if lock.acquire(blocking=False):
+        return 0.0
+    t0 = time.perf_counter()
+    with active_tracer().span(kind, kind=kind):
+        lock.acquire()
+    waited = time.perf_counter() - t0
+    _tls.lock_wait_s = lock_wait_s() + waited
+    return waited
+
+
+def lock_wait_s() -> float:
+    """Seconds this thread has spent blocked in `acquire_timed`."""
+    return getattr(_tls, "lock_wait_s", 0.0)
 
 
 class TraceRing:

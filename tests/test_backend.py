@@ -294,17 +294,65 @@ def test_unknown_request_id_raises(static_engine):
 
 
 def test_backend_stats_and_roofline(cont_engine):
+    """Step counters and the step loop's own clock on a ragged wave.  (The
+    roofline bound this test once read is gone; the name stays.)"""
     reqs = _ragged_workload()[:4]
+    before = cont_engine.backend_stats()
     cont_engine.submit_batch(copy.deepcopy(reqs))
     stats = cont_engine.backend_stats()
     assert stats["backend"] == "continuous"
     assert stats["prefill_steps"] > 0 and stats["decode_steps"] > 0
     assert stats["kv_peak_blocks"] > 0
-    # the CPU has no published peaks: no bound is divided out for it
-    with pytest.raises(ValueError, match="no published peaks"):
-        cont_engine.backend_roofline()
+
+    def delta(key):
+        return stats[key] - before[key]
+
+    # the four fit the slots at once, so every prefill step carries each
+    # sequence still prefilling: one row per chunk of each prompt
+    chunk = cont_engine._batcher.prefill_chunk
+    lens = [len(tok.encode(r.prompt, max_len=cont_engine.max_seq))
+            for r in reqs]
+    assert delta("prefill_rows") == sum(-(-n // chunk) for n in lens)
+    assert delta("prefill_tokens") == sum(lens)
+    assert delta("prefill_rows") <= delta("prefill_steps") * cont_engine.max_batch
+    assert stats["loop_s"] >= stats["readback_s"] >= 0
+    assert delta("loop_s") >= delta("readback_s") > 0
 
     # the static backend reports too, without batcher telemetry
     st = JaxInferenceEngine("proxy-8b", smoke=True, backend="static")
     assert st.backend_stats()["backend"] == "static"
-    assert st.backend_roofline() == {}
+    assert "prefill_rows" not in st.backend_stats()
+
+
+def test_step_programs_carry_pinned_names(cont_engine):
+    """The jitted steps lower to modules named for ``_prefill_fn`` and
+    ``_decode_fn``, which device-trace readers match."""
+    import jax
+    cont_engine.submit_batch(copy.deepcopy(_ragged_workload()[:2]))
+    b = cont_engine._batcher
+
+    def like(x):
+        return jax.ShapeDtypeStruct(x.shape, x.dtype)
+
+    def ints(*shape):
+        return jax.ShapeDtypeStruct(shape, np.int32)
+
+    common = (jax.tree.map(like, cont_engine.params),
+              jax.tree.map(like, b.kv.pool))
+    seen = set()
+    for key, fn in cont_engine._jit_cache.items():
+        if key[0] == "cb_prefill":
+            _, slots, chunk, nb, _ = key
+            args = common + (ints(slots, nb), ints(slots), ints(slots),
+                             ints(slots, chunk))
+            want = "jit__prefill_fn"
+        elif key[0] == "cb_decode":
+            _, slots, nb, _ = key
+            args = common + (ints(slots, nb), ints(slots), ints(slots),
+                             ints(slots, 1))
+            want = "jit__decode_fn"
+        else:
+            continue
+        assert f"module @{want}" in fn.lower(*args).as_text()
+        seen.add(want)
+    assert seen == {"jit__prefill_fn", "jit__decode_fn"}

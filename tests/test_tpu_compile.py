@@ -8,6 +8,7 @@ Interpret-mode tests cannot see what Mosaic refuses (unaligned block
 shapes, too much fast memory); these can, at no chip time.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -55,6 +56,10 @@ def test_decode_attention_kernel_compiles_for_v5e(one_chip, smax):
         one_chip, ((B, H, HD), jnp.bfloat16), ((B, KV, smax, HD), jnp.bfloat16),
         ((B, KV, smax, HD), jnp.bfloat16), ((B,), jnp.int32))
     assert "tpu_custom_call" in text
+    # the call keeps its name in the compiled program, where a device
+    # trace's reader finds it
+    assert re.search(r'%flash_decode[.\d]* = .*custom_call_target='
+                     r'"tpu_custom_call"', text)
 
 
 @pytest.mark.parametrize("smax", [32, 2048])
