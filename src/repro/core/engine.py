@@ -98,8 +98,8 @@ class QueryReport:
     # observability.md for the span taxonomy and export formats
     trace: Optional[Dict[str, Any]] = None
     # seconds this query's thread spent blocked on the request
-    # pipeline's and the scheduler's dispatch locks, i.e. queued behind
-    # other queries' engine batches
+    # pipeline's and the scheduler's locks (they guard state and are not
+    # held across an engine call)
     lock_wait_s: float = 0.0
 
     def explain_analyze(self) -> str:

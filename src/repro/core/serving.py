@@ -5,7 +5,8 @@ engine (paper §2's "heavy traffic" premise made concrete).
 catalog + scheduler into a **serving runtime** that keeps N queries in
 flight at once while sharing the expensive state across all of them:
 
-  * one `RequestPipeline` (thread-safe, single-dispatcher) shared by
+  * one `RequestPipeline` (thread-safe; its lock is not held across
+    the engine call, so sessions' batches dispatch together) shared by
     every session, so coalescing, dedup and the TTL'd LRU result cache
     work **across** concurrent queries and tenants — the repeated
     predicates of a production workload are answered once and served

@@ -33,19 +33,35 @@ bitwise equal to the dense cache attention.  The parity tests in
 and shape-dependent MXU sums move SCOREs by about 1e-2 even within the
 static path; ``chip_smoke.py`` holds the chip to stated tolerances.
 
-Tracing: the loop's phases are spans (``engine.wave``, ``engine.tokenize``,
-``engine.admit``, ``engine.prefill_step``, ``engine.decode_step``,
-``engine.readback``, ``engine.retire``; see ``repro.obs.trace``), so a
-``jax.profiler`` trace names what the host did in each device idle gap.
-The step spans time the host side only, building inputs and dispatching;
-the device's time is the profiler's.  ``stats()`` adds the wall seconds
-inside ``serve`` (``loop_s``) and those blocked in readbacks
-(``readback_s``).  Nothing here synchronizes with the device beyond the
+Callers share one step loop.  ``serve`` enqueues its sequences on one
+batcher-wide FIFO; the caller that finds no loop running becomes the
+*driver* and steps every caller's sequences, while the others wait until
+their own have retired.  A driver drives only until its own sequences
+have retired, then hands the loop to a waiting caller, so no caller
+serves other callers' work for ever.  Admission stays strict FIFO in
+enqueue order across callers; mixing callers in one step is safe because
+of the determinism contract above: a row's result does not depend on
+what shares its step.  A lone caller runs exactly the steps it would run
+alone.
+
+Tracing: the loop's phases are spans (``engine.wave``, ``engine.join``,
+``engine.tokenize``, ``engine.admit``, ``engine.prefill_step``,
+``engine.decode_step``, ``engine.readback``, ``engine.retire``; see
+``repro.obs.trace``), so a ``jax.profiler`` trace names what the host did
+in each device idle gap.  ``engine.wave`` is a driver's stretch at the
+loop, ``engine.join`` a caller waiting for its sequences on another
+caller's loop.  The step spans time the host side only, building inputs
+and dispatching; the device's time is the profiler's.  ``stats()`` adds
+the wall seconds the loop ran, counted once whoever drove it
+(``loop_s``), those blocked in readbacks (``readback_s``), and the
+sequences admitted while another caller's were live or pending
+(``joined``).  Nothing here synchronizes with the device beyond the
 readbacks the loop needs anyway.
 """
 from __future__ import annotations
 
 import dataclasses
+import threading
 import time
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional, Sequence
@@ -79,6 +95,17 @@ def _pow2(n: int) -> int:
     return b
 
 
+class _Caller:
+    """One ``serve`` call: its results, its clock, its sequences left."""
+    __slots__ = ("t0", "results", "left", "error")
+
+    def __init__(self, n: int, t0: float):
+        self.t0 = t0
+        self.results: List[Optional[Result]] = [None] * n
+        self.left = n              # sequences not yet retired
+        self.error: Optional[BaseException] = None
+
+
 @dataclasses.dataclass
 class _Seq:
     """One in-flight sequence (a slot's occupant)."""
@@ -91,6 +118,7 @@ class _Seq:
     filled: int = 0            # prompt tokens already in the paged cache
     cur: int = -1              # last sampled token (next decode input)
     out: List[int] = dataclasses.field(default_factory=list)
+    caller: Optional[_Caller] = None
 
 
 class ContinuousBatcher:
@@ -98,7 +126,10 @@ class ContinuousBatcher:
 
     Owns no model/params — it drives the engine's model through two jitted
     step functions (shared via ``engine._jit`` so compile counting and
-    caching live in one place).
+    caching live in one place).  Safe for concurrent ``serve`` calls,
+    which share the loop (module docstring): ``_cv`` guards the pending
+    queue and the callers' hand-off; the slots, the pool and the step
+    state belong to whichever caller drives.
     """
 
     def __init__(self, engine, *, block_size: int = 32,
@@ -124,9 +155,15 @@ class ContinuousBatcher:
         # device mirror of (block tables, lengths, decode-active mask),
         # valid between slot mutations — see _device_state
         self._dev: Optional[Dict[str, Any]] = None
+        self._cv = threading.Condition()
+        self._pending: Deque[_Seq] = deque()   # every caller's, FIFO
+        self._active: List[Optional[_Seq]] = [None] * self.slots
+        self._callers = 0          # callers with sequences live or pending
+        self._driving = False      # a caller is running the step loop
         # telemetry
         self.waves = 0             # serve() calls
         self.admitted = 0          # sequences admitted into slots
+        self.joined = 0            # of which beside another caller's
         self.retired = 0
         self.retired_eos = 0       # retired on EOS before max_tokens
         self.prefill_steps = 0
@@ -135,7 +172,7 @@ class ContinuousBatcher:
         self.prefill_tokens = 0    # prompt tokens written via chunked prefill
         self.decode_tokens = 0     # decode-step slot participations
         self.peak_blocks = 0
-        self.loop_s = 0.0          # wall seconds inside serve()
+        self.loop_s = 0.0          # wall seconds the step loop ran
         self.readback_s = 0.0      # of which blocked reading step outputs
 
     # ------------------------------------------------------------------
@@ -145,35 +182,105 @@ class ContinuousBatcher:
     def serve(self, requests: Sequence[Request],
               t0: Optional[float] = None) -> List[Result]:
         """Serve SCORE/COMPLETE requests to completion; returns results in
-        submission order with per-request completion-time latency."""
-        start = time.perf_counter()
-        t0 = start if t0 is None else t0
+        submission order with per-request completion-time latency (from
+        ``t0``).  Tokenizes outside any lock, enqueues, then drives the
+        step loop or joins the one another caller drives.  A request that
+        can never fit the pool raises `EngineFailure` here, before any of
+        the call's requests is enqueued."""
+        t0 = time.perf_counter() if t0 is None else t0
         tr = active_tracer()
-        self.waves += 1
+        caller = _Caller(len(requests), t0)
+        with tr.span("engine.tokenize", kind="engine.tokenize"):
+            seqs = [_Seq(req=r, index=i, slot=-1, blocks=[], caller=caller,
+                         enc=tok.encode(r.prompt, max_len=self.engine.max_seq))
+                    for i, r in enumerate(requests)]
+        for seq in seqs:
+            need = self._blocks_needed(seq)
+            if need > self.kv.max_seq_blocks:
+                raise EngineFailure(
+                    f"{self.engine.engine_id}: request {seq.req.request_id} "
+                    f"needs {need} KV blocks, pool holds "
+                    f"{self.kv.max_seq_blocks} (raise kv_blocks)")
+        with self._cv:
+            self.waves += 1
+            if not seqs:
+                return []
+            self._pending.extend(seqs)
+            self._callers += 1
+            drive = not self._driving
+            self._driving = True
+        if not drive:
+            with tr.span("engine.join", kind="engine.join",
+                         requests=len(requests)):
+                drive = self._wait(caller)
+        if drive:
+            self._drive(caller, tr)
+        if caller.error is not None:
+            raise caller.error
+        return caller.results  # type: ignore[return-value]
+
+    def _wait(self, caller: _Caller) -> bool:
+        """Block until ``caller``'s sequences have retired (False) or the
+        loop is handed to it with some still to serve (True)."""
+        with self._cv:
+            while caller.left and caller.error is None and self._driving:
+                self._cv.wait()
+            if not caller.left or caller.error is not None:
+                return False
+            self._driving = True
+            return True
+
+    def _drive(self, caller: _Caller, tr) -> None:
+        """Run the step loop over every caller's sequences until
+        ``caller``'s own have retired, then hand the loop on."""
+        start = time.perf_counter()
+        active = self._active
         try:
             with tr.span("engine.wave", kind="engine.wave",
-                         requests=len(requests)):
-                return self._serve(requests, t0, tr)
+                         requests=len(caller.results)):
+                while True:
+                    with self._cv:
+                        if not caller.left:
+                            break
+                        with tr.span("engine.admit", kind="engine.admit"):
+                            self._admit(self._pending, active)
+                    if any(s is not None and s.state == "prefill"
+                           for s in active):
+                        self._prefill_step(active)
+                    if any(s is not None and s.state == "decode"
+                           for s in active):
+                        self._decode_step(active)
+        except BaseException as e:
+            self._abort(caller, e)
+            raise
         finally:
             self.loop_s += time.perf_counter() - start
+            with self._cv:
+                self._driving = False
+                self._cv.notify_all()
 
-    def _serve(self, requests, t0, tr) -> List[Result]:
-        pending: Deque[_Seq] = deque()
-        with tr.span("engine.tokenize", kind="engine.tokenize"):
-            for i, r in enumerate(requests):
-                enc = tok.encode(r.prompt, max_len=self.engine.max_seq)
-                pending.append(_Seq(req=r, index=i, enc=enc, slot=-1,
-                                    blocks=[]))
-        active: List[Optional[_Seq]] = [None] * self.slots
-        results: List[Optional[Result]] = [None] * len(requests)
-        while pending or any(s is not None for s in active):
-            with tr.span("engine.admit", kind="engine.admit"):
-                self._admit(pending, active)
-            if any(s is not None and s.state == "prefill" for s in active):
-                self._prefill_step(active, results, t0)
-            if any(s is not None and s.state == "decode" for s in active):
-                self._decode_step(active, results, t0)
-        return results  # type: ignore[return-value]
+    def _abort(self, driver: _Caller, error: BaseException) -> None:
+        """The loop failed under ``driver``: fail every other caller with
+        sequences in the batcher, and empty the slots and the queue."""
+        with self._cv:
+            seqs = list(self._pending) + [s for s in self._active
+                                          if s is not None]
+            for s in seqs:
+                if s.caller is not driver and s.caller.error is None:
+                    s.caller.error = EngineFailure(
+                        f"{self.engine.engine_id}: the step loop failed "
+                        f"under another caller: {error!r}")
+                    s.caller.error.__cause__ = error
+            for s in self._active:
+                if s is not None:
+                    self.kv.free_blocks(s.blocks)
+            self._pending.clear()
+            self._active[:] = [None] * self.slots
+            self.tables_np[:] = 0
+            self.lens_np[:] = 0
+            self._dev = None
+            self._callers = 0
+            self._cv.notify_all()
 
     def _readback(self, x, dtype) -> np.ndarray:
         """A step output on the host: blocks until the device computed it."""
@@ -193,22 +300,17 @@ class ContinuousBatcher:
 
     def _admit(self, pending: Deque[_Seq], active: List[Optional[_Seq]]
                ) -> int:
-        """FIFO admission into free slots while KV blocks last.  Head-of-
-        line order is kept deliberately: skipping ahead would make results
-        depend on pool pressure, and the determinism contract forbids it
-        (per-row results are batch-independent, so order alone is enough).
+        """FIFO admission into free slots while KV blocks last, in enqueue
+        order across callers (held under ``_cv``).  Head-of-line order is
+        kept deliberately: skipping ahead would make results depend on
+        pool pressure, and the determinism contract forbids it (per-row
+        results are batch-independent, so order alone is enough).
         """
         n = 0
         free_slots = [i for i, s in enumerate(active) if s is None]
         while pending and free_slots:
             seq = pending[0]
             need = self._blocks_needed(seq)
-            if need > self.kv.max_seq_blocks:
-                pending.popleft()
-                raise EngineFailure(
-                    f"{self.engine.engine_id}: request {seq.req.request_id} "
-                    f"needs {need} KV blocks, pool holds "
-                    f"{self.kv.max_seq_blocks} (raise kv_blocks)")
             if not self.kv.can_alloc(need):
                 break
             pending.popleft()
@@ -222,6 +324,8 @@ class ContinuousBatcher:
         if n:
             self._dev = None
         self.admitted += n
+        if self._callers > 1:
+            self.joined += n
         used = self.kv.num_blocks - 1 - self.kv.free_count
         self.peak_blocks = max(self.peak_blocks, used)
         return n
@@ -269,7 +373,7 @@ class ContinuousBatcher:
     # batched chunked prefill
     # ------------------------------------------------------------------
 
-    def _prefill_step(self, active, results, t0) -> None:
+    def _prefill_step(self, active) -> None:
         C = self.prefill_chunk
         B = self.slots
         pre = [s for s in active if s is not None and s.state == "prefill"]
@@ -301,7 +405,7 @@ class ContinuousBatcher:
             if s.filled >= len(s.enc):
                 if lf is None:
                     lf = self._readback(logits, np.float32)
-                self._finish_prefill(s, lf[s.slot], active, results, t0)
+                self._finish_prefill(s, lf[s.slot], active)
 
     def _prefill_fn(self, params, pool, tables, lens, counts, toks):
         cache = self.kv.gather(pool, tables, lens)
@@ -315,26 +419,26 @@ class ContinuousBatcher:
         pool = self.kv.scatter(pool, out["cache"], tables, lens, counts, C)
         return pool, logits, lens + counts
 
-    def _finish_prefill(self, s: _Seq, logits_row: np.ndarray, active,
-                        results, t0) -> None:
+    def _finish_prefill(self, s: _Seq, logits_row: np.ndarray, active
+                        ) -> None:
         r = s.req
         if r.kind == SCORE:
             # the static _score_batch's arithmetic on these logits
             py = logits_row[tok.YES_ID]
             pn = logits_row[tok.NO_ID]
             score = 1.0 / (1.0 + np.exp(-(py - pn)))
-            self._retire(s, active, results, t0, score=float(score))
+            self._retire(s, active, score=float(score))
             return
         s.cur = int(np.argmax(logits_row))
         s.state = "decode"
         self._dev = None        # slot joins the decode-active mask
-        self._consume(s, active, results, t0)
+        self._consume(s, active)
 
     # ------------------------------------------------------------------
     # decode step
     # ------------------------------------------------------------------
 
-    def _decode_step(self, active, results, t0) -> None:
+    def _decode_step(self, active) -> None:
         B = self.slots
         dec = [s for s in active if s is not None and s.state == "decode"]
         with active_tracer().span("engine.decode_step",
@@ -357,7 +461,7 @@ class ContinuousBatcher:
         nxt = self._readback(nxt_dev, np.int32)
         for s in dec:
             s.cur = int(nxt[s.slot])
-            self._consume(s, active, results, t0)
+            self._consume(s, active)
 
     def _decode_fn(self, params, pool, tables, lens, act, cur):
         cache = self.kv.gather(pool, tables, lens)
@@ -368,19 +472,21 @@ class ContinuousBatcher:
         pool = self.kv.scatter(pool, out["cache"], tables, lens, act, 1)
         return pool, jnp.argmax(logits, -1), lens + act
 
-    def _consume(self, s: _Seq, active, results, t0) -> None:
+    def _consume(self, s: _Seq, active) -> None:
         """Append the sampled token and retire on EOS / max_tokens —
         exactly the static loop's append-then-check chain."""
         s.out.append(s.cur)
         if s.cur == tok.EOS_ID or len(s.out) >= s.req.max_tokens:
             if s.cur == tok.EOS_ID and len(s.out) < s.req.max_tokens:
                 self.retired_eos += 1
-            self._retire(s, active, results, t0)
+            self._retire(s, active)
 
     # ------------------------------------------------------------------
 
-    def _retire(self, s: _Seq, active, results, t0,
-                score: Optional[float] = None) -> None:
+    def _retire(self, s: _Seq, active, score: Optional[float] = None
+                ) -> None:
+        """Free ``s``'s slot and blocks, and hand its result to its
+        caller; the caller's last one wakes it."""
         with active_tracer().span("engine.retire", kind="engine.retire"):
             r = s.req
             eng = self.engine
@@ -395,14 +501,20 @@ class ContinuousBatcher:
                              tokens_out=len(s.out),
                              credits=credits_for(eng.arch, ti + len(s.out)),
                              engine_id=eng.engine_id)
-            res.latency_s = time.perf_counter() - t0
-            results[s.index] = res
+            caller = s.caller
+            res.latency_s = time.perf_counter() - caller.t0
+            caller.results[s.index] = res
             self.kv.free_blocks(s.blocks)
             active[s.slot] = None
             self.lens_np[s.slot] = 0
             self.tables_np[s.slot, :] = 0
             self._dev = None
             self.retired += 1
+            with self._cv:
+                caller.left -= 1
+                if not caller.left:
+                    self._callers -= 1
+                    self._cv.notify_all()
 
     # ------------------------------------------------------------------
     # telemetry
@@ -413,6 +525,7 @@ class ContinuousBatcher:
                if self.decode_steps else 0.0)
         return {
             "waves": self.waves, "admitted": self.admitted,
+            "joined": self.joined,
             "retired": self.retired, "retired_eos": self.retired_eos,
             "prefill_steps": self.prefill_steps,
             "decode_steps": self.decode_steps,

@@ -35,6 +35,7 @@ deterministic pseudo-embeddings derived from the URI hash.
 from __future__ import annotations
 
 import hashlib
+import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
@@ -134,6 +135,9 @@ class JaxInferenceEngine:
                 jax.random.PRNGKey(seed))
         self._jit_cache: Dict[Any, Any] = {}
         self.jit_compiles = 0      # distinct jit entries (compile proxy)
+        # batches dispatch concurrently (static kinds beside the step
+        # loop): this guards the jit cache's inserts and the meters below
+        self._lock = threading.Lock()
         # decode backend: continuous batching wherever the architecture
         # supports a paged cache, unless explicitly pinned
         if backend == "auto":
@@ -203,11 +207,16 @@ class JaxInferenceEngine:
         jitted = self._jit_cache.get(key)
         if jitted is not None:
             return jitted
-        self.jit_compiles += 1
-        name = _PROGRAM_NAMES.get(key[0])
-        if name is not None:
-            fn = _named(fn, name)
-        jitted = self._jit_cache[key] = jax.jit(fn, donate_argnums=donate)
+        with self._lock:
+            jitted = self._jit_cache.get(key)
+            if jitted is not None:
+                return jitted
+            self.jit_compiles += 1
+            name = _PROGRAM_NAMES.get(key[0])
+            if name is not None:
+                fn = _named(fn, name)
+            jitted = self._jit_cache[key] = jax.jit(fn,
+                                                    donate_argnums=donate)
         return _first_call(key, jitted)
 
     def _prefill(self, requests: Sequence[Request], cap: Optional[int] = None,
@@ -455,6 +464,10 @@ class JaxInferenceEngine:
     # ------------------------------------------------------------------
 
     def submit_batch(self, requests: Sequence[Request]) -> List[Result]:
+        """Serve one batch; safe to call from several threads at once.
+        SCORE/COMPLETE join the continuous batcher's shared step loop;
+        static-path batches run on the calling thread beside it, reading
+        only ``params`` (the KV pool is the batcher's)."""
         if self.failure_rate and self._rng.random() < self.failure_rate:
             raise EngineFailure(f"{self.engine_id}: injected fault")
         if self.straggle_s:
@@ -484,10 +497,11 @@ class JaxInferenceEngine:
                         out.extend(self._embed_batch(chunk, t0))
                     else:
                         out.extend(self._complete_batch(chunk, t0))
-        for r in out:
-            self.total_credits += r.credits
-            self.total_tokens += r.tokens_in + r.tokens_out
-        self.total_requests += len(requests)
+        with self._lock:
+            for r in out:
+                self.total_credits += r.credits
+                self.total_tokens += r.tokens_in + r.tokens_out
+            self.total_requests += len(requests)
         return self._restore_order(requests, out)
 
     def _restore_order(self, requests: Sequence[Request],

@@ -42,18 +42,22 @@ models' (and other sessions') queues keep coalescing.
 ``flush(owner=...)`` is the serving engine's per-session barrier: it
 dispatches only that owner's queued items.
 
-Concurrency model: **single-dispatcher via one reentrant lock**.  Every
-public entry point (submit, flush, cancel) acquires ``self._lock`` for
-its full duration, including the engine dispatch — so queue, dedup
-table, cache and stats mutations are always serialized, duplicate
-futures can never attach to an item mid-resolution, and a ``result()``
-call racing a dispatch simply blocks on the lock until its future is
-resolved.  Concurrency wins come from coalescing and caching *across*
-the querying threads, not from parallel dispatch; the backends model
-batch-parallel execution internally.  A thread that finds the lock held
-waits as a ``pipeline.lock_wait`` span; ``PipelineStats.lock_wait_s``
-and ``lock_waits`` count those waits, and the waiting query's
-``QueryReport.lock_wait_s`` carries its share.
+Concurrency model: **one reentrant lock guards state, not the engine
+call**.  Every public entry point (submit, flush, cancel) holds
+``self._lock`` while it reads or mutates the queues, the dedup table,
+the cache, the meters and the stats.  A dispatch pops its items under
+the lock, releases it around ``Scheduler.submit`` and the retry/backoff
+loop, and re-takes it to count, bill, cache, drop the items' in-flight
+fingerprints and resolve their futures.  So several threads' batches
+reach the engines at once (a continuous-batching engine admits them
+into one running step loop), while every state change stays serialized.
+An item's fingerprint stays in the in-flight table until it resolves,
+so a duplicate arriving mid-dispatch still attaches to it; a
+``result()`` on a future whose item another thread is dispatching waits
+on the future's event until that thread resolves it.  A thread that
+finds the lock held waits as a ``pipeline.lock_wait`` span;
+``PipelineStats.lock_wait_s`` and ``lock_waits`` count those waits, and
+the waiting query's ``QueryReport.lock_wait_s`` carries its share.
 """
 from __future__ import annotations
 
@@ -93,12 +97,14 @@ def request_fingerprint(r: Request) -> Tuple:
 
 class ResultFuture:
     """Handle for one in-flight request.  ``result()`` forces a barrier
-    flush of the owning pipeline if the request has not been dispatched.
-    A future whose request was cancelled before dispatch (see
-    `RequestPipeline.cancel`) or permanently failed (retries exhausted)
-    raises `RequestFailed` on ``result()``."""
+    flush of the owning pipeline if the request has not been dispatched,
+    and waits for it if another thread is dispatching it.  A future whose
+    request was cancelled before dispatch (see `RequestPipeline.cancel`)
+    or permanently failed (retries exhausted) raises `RequestFailed` on
+    ``result()``."""
 
-    __slots__ = ("_pipeline", "_result", "_cancelled", "_error", "_model")
+    __slots__ = ("_pipeline", "_result", "_cancelled", "_error", "_model",
+                 "_settled")
 
     def __init__(self, pipeline: Optional["RequestPipeline"] = None,
                  model: Optional[str] = None):
@@ -107,6 +113,9 @@ class ResultFuture:
         self._cancelled = False
         self._error: Optional[Exception] = None
         self._model = model           # scopes the barrier flush
+        # set once resolved, failed or cancelled (a future made resolved
+        # has nothing to wait for, and no event)
+        self._settled = threading.Event() if pipeline is not None else None
 
     @classmethod
     def resolved(cls, result: Result) -> "ResultFuture":
@@ -123,11 +132,21 @@ class ResultFuture:
     def exception(self) -> Optional[Exception]:
         return self._error
 
+    def _settle(self) -> None:
+        if self._settled is not None:
+            self._settled.set()
+
     def _resolve(self, result: Result) -> None:
         self._result = result
+        self._settle()
 
     def _fail(self, error: Exception) -> None:
         self._error = error
+        self._settle()
+
+    def _cancel(self) -> None:
+        self._cancelled = True
+        self._settle()
 
     def result(self) -> Result:
         if self._cancelled:
@@ -141,12 +160,13 @@ class ResultFuture:
             # models' (and on a shared pipeline, other sessions')
             # queues keep coalescing
             self._pipeline.flush(self._model)
-            if self._result is None and self._error is None:
-                self._pipeline.flush()    # defensive full barrier
+            # not resolved by that flush: the request had left the queue
+            # already, and another thread is dispatching it
+            self._settled.wait()
+            if self._cancelled:
+                raise RequestFailed("request was cancelled before dispatch")
         if self._error is not None:
             raise self._error
-        if self._result is None:      # pragma: no cover - defensive
-            raise RuntimeError("pipeline flush did not resolve future")
         return self._result
 
 
@@ -163,8 +183,8 @@ class PipelineConfig:
     # SchedulerError, e.g. every replica faulted) is re-dispatched up to
     # max_retries more times with exponential backoff; after that the
     # affected futures resolve with RequestFailed (clean error, no hang).
-    # NB: the backoff sleep runs inside the single-dispatcher lock, so
-    # it pauses every session — keep base * 2^max_retries small
+    # The backoff sleeps with the pipeline's lock released; the items
+    # it retries wait, other sessions' work does not
     max_retries: int = 2
     retry_backoff_s: float = 0.002       # base backoff (doubles per retry)
     retry_backoff_cap_s: float = 0.25    # backoff ceiling
@@ -186,7 +206,7 @@ class PipelineStats:
     retries: int = 0              # batch re-dispatches after a fault
     failures: int = 0             # requests that exhausted their retries
     queue_wait_s: float = 0.0     # sum over dispatched reqs of queue time
-    lock_waits: int = 0           # entries that blocked on the dispatch lock
+    lock_waits: int = 0           # entries that blocked on the lock
     lock_wait_s: float = 0.0      # seconds those entries waited for it
     batch_size_hist: Dict[int, int] = dataclasses.field(default_factory=dict)
     # submissions per request kind (score/classify/complete): lets the
@@ -284,17 +304,31 @@ class RequestPipeline:
         with self._dispatch_lock():
             self._meters[owner] = fn
 
+    def _acquire(self) -> None:
+        """Take ``self._lock``; a wait for it is timed and counted."""
+        waited = acquire_timed(self._lock, "pipeline.lock_wait")
+        if waited:
+            self.stats.lock_waits += 1
+            self.stats.lock_wait_s += waited
+
     @contextmanager
     def _dispatch_lock(self):
-        """Hold ``self._lock``; a wait for it is timed and counted."""
-        waited = acquire_timed(self._lock, "pipeline.lock_wait")
+        """Hold ``self._lock``."""
+        self._acquire()
         try:
-            if waited:
-                self.stats.lock_waits += 1
-                self.stats.lock_wait_s += waited
             yield
         finally:
             self._lock.release()
+
+    @contextmanager
+    def _released(self):
+        """Release the held ``self._lock`` around an engine call, and take
+        it again after (timed, like any entry)."""
+        self._lock.release()
+        try:
+            yield
+        finally:
+            self._acquire()
 
     # ------------------------------------------------------------------
     # submission
@@ -434,7 +468,7 @@ class RequestPipeline:
                             continue
                         for f in mine:
                             item.futures.remove(f)
-                            f._cancelled = True
+                            f._cancel()
                         others = [o for o in item.owners if o != owner]
                         if item.owner == owner and others:
                             item.owner = others[0]
@@ -471,7 +505,7 @@ class RequestPipeline:
                 if should_cancel(item):
                     cancelled += 1
                     for f in item.futures:
-                        f._cancelled = True
+                        f._cancel()
                     if self.cfg.dedup:
                         self._inflight.pop(
                             request_fingerprint(item.request), None)
@@ -542,21 +576,26 @@ class RequestPipeline:
                 tr.event("pipeline.coalesce", requests=len(items))
             results: Optional[List[Result]] = None
             last_exc: Optional[Exception] = None
-            for attempt in range(self.cfg.max_retries + 1):
-                if attempt:
-                    # transient fault: back off, then re-dispatch the
-                    # same batch (the scheduler re-picks replicas
-                    # underneath)
-                    self.stats.retries += 1
-                    tr.event("pipeline.retry", attempt=attempt)
-                    time.sleep(min(
-                        self.cfg.retry_backoff_s * (2 ** (attempt - 1)),
-                        self.cfg.retry_backoff_cap_s))
-                try:
-                    results = self.scheduler.submit(requests)
-                    break
-                except (EngineFailure, SchedulerError) as e:
-                    last_exc = e
+            attempt = 0
+            try:
+                with self._released():
+                    for attempt in range(self.cfg.max_retries + 1):
+                        if attempt:
+                            # transient fault: back off, then re-dispatch
+                            # the same batch (the scheduler re-picks
+                            # replicas underneath)
+                            tr.event("pipeline.retry", attempt=attempt)
+                            time.sleep(min(
+                                self.cfg.retry_backoff_s
+                                * (2 ** (attempt - 1)),
+                                self.cfg.retry_backoff_cap_s))
+                        try:
+                            results = self.scheduler.submit(requests)
+                            break
+                        except (EngineFailure, SchedulerError) as e:
+                            last_exc = e
+            finally:
+                self.stats.retries += attempt
             if tr.enabled and results is not None:
                 waits = [it.trace_t0 for it in items
                          if it.trace_t0 is not None]

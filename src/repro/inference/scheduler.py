@@ -26,6 +26,7 @@ from __future__ import annotations
 
 import threading
 import time
+from contextlib import contextmanager
 from typing import Dict, List, Optional, Sequence
 
 from repro.inference.backend import (EngineFailure, EngineTimeout,
@@ -61,10 +62,9 @@ class Scheduler:
         self.max_retries = max_retries
         self.straggler_deadline_s = straggler_deadline_s
         self.straggler_penalty_s = straggler_penalty_s
-        # one submit at a time: routing state (_busy_s/_depth/_rr), the
-        # telemetry counters and the backends' own meters are all
-        # mutated per call — concurrent querying threads serialize here
-        # (the single-dispatcher half of the serving concurrency model)
+        # guards routing state (_busy_s/_depth/_rr) and the telemetry
+        # counters; held for picks and bookkeeping, never across an
+        # engine call (backends serialize what they must themselves)
         self._lock = threading.RLock()
         # telemetry
         self.retries = 0
@@ -164,18 +164,22 @@ class Scheduler:
         self._rr[model] = i + 1
         return tied[i]
 
-    def submit(self, requests: Sequence[Request]) -> List[Result]:
-        """Route a mixed-model batch; preserves input order.  Thread-safe
-        (serialized on the scheduler lock; a wait for it is a
-        ``scheduler.lock_wait`` span)."""
+    @contextmanager
+    def _locked(self):
+        """Hold the scheduler lock; a wait for it is a
+        ``scheduler.lock_wait`` span."""
         acquire_timed(self._lock, "scheduler.lock_wait")
         try:
-            return self._submit_locked(requests)
+            yield
         finally:
             self._lock.release()
 
-    def _submit_locked(self, requests: Sequence[Request]) -> List[Result]:
-        self.submits += 1
+    def submit(self, requests: Sequence[Request]) -> List[Result]:
+        """Route a mixed-model batch; preserves input order.  Thread-safe:
+        the lock covers picks and bookkeeping, not the engine call, so
+        concurrent submits reach their engines together."""
+        with self._locked():
+            self.submits += 1
         originals = self._ensure_unique_ids(requests)
         try:
             by_model: Dict[str, List[Request]] = {}
@@ -183,7 +187,9 @@ class Scheduler:
                 by_model.setdefault(r.model, []).append(r)
             results: Dict[int, Result] = {}
             for model, reqs in by_model.items():
-                for part in self._partition(model, reqs):
+                with self._locked():
+                    parts = self._partition(model, reqs)
+                for part in parts:
                     for res in self._submit_one_model(model, part):
                         results[res.request_id] = res
             out = [results[r.request_id] for r in requests]
@@ -259,10 +265,13 @@ class Scheduler:
                           ) -> List[Result]:
         last_exc: Optional[Exception] = None
         tr = active_tracer()
-        engine = self._pick(model)
+        with self._locked():
+            engine = self._pick(model)
         for attempt in range(self.max_retries + 1):
             eid = id(engine)
-            self._depth[eid] = self._depth.get(eid, 0) + len(reqs)
+            with self._locked():
+                self._depth[eid] = self._depth.get(eid, 0) + len(reqs)
+                self.dispatches += 1
             try:
                 with tr.span("dispatch.replica", kind="dispatch.replica",
                              model=model,
@@ -271,7 +280,6 @@ class Scheduler:
                              attempt=attempt,
                              requests=len(reqs)) as sp:
                     t0 = time.perf_counter()
-                    self.dispatches += 1
                     out = engine.submit_batch(reqs)
                     dt = time.perf_counter() - t0
                     if tr.enabled:
@@ -282,27 +290,32 @@ class Scheduler:
                                                   for r in out)),
                                outcome="ok")
                 self._record_dispatch(model, out, dt)
-                self._busy_s[eid] = self._busy_s.get(eid, 0.0) + dt
-                if (self.straggler_deadline_s is not None
-                        and dt > self.straggler_deadline_s
-                        and len(self._replicas.get(model, ())) > 1
-                        and attempt < self.max_retries):
-                    # straggler: result arrived but too late — penalize the
-                    # slow replica so least-loaded picks route around it
-                    self.redispatches += 1
-                    self._busy_s[eid] += self.straggler_penalty_s
+                with self._locked():
+                    self._busy_s[eid] = self._busy_s.get(eid, 0.0) + dt
+                    if (self.straggler_deadline_s is not None
+                            and dt > self.straggler_deadline_s
+                            and len(self._replicas.get(model, ())) > 1
+                            and attempt < self.max_retries):
+                        # straggler: result arrived but too late —
+                        # penalize the slow replica so least-loaded picks
+                        # route around it
+                        self.redispatches += 1
+                        self._busy_s[eid] += self.straggler_penalty_s
                 return out
             except EngineFailure as e:
                 last_exc = e
-                self.retries += 1
                 timeout = isinstance(e, EngineTimeout)
-                if timeout:
-                    self.timeouts += 1
                 sp.set(outcome="timeout" if timeout else "fault")
                 tr.event("scheduler.retry", attempt=attempt,
                          timeout=timeout)
-                engine = self._pick(model, exclude=engine)
+                with self._locked():
+                    self.retries += 1
+                    if timeout:
+                        self.timeouts += 1
+                    engine = self._pick(model, exclude=engine)
             finally:
-                self._depth[eid] = max(self._depth.get(eid, 0) - len(reqs), 0)
+                with self._locked():
+                    self._depth[eid] = max(
+                        self._depth.get(eid, 0) - len(reqs), 0)
         raise SchedulerError(
             f"model {model}: exhausted {self.max_retries} retries") from last_exc

@@ -41,10 +41,11 @@ SPAN_KINDS = {
     "predicate": "one AI predicate evaluated over a row batch",
     "cascade": "proxy/oracle cascade run for one predicate batch",
     "pipeline.dispatch": "one coalesced batch leaving the request pipeline",
-    "pipeline.lock_wait": "a submit or flush blocked on the pipeline's dispatch lock",
-    "scheduler.lock_wait": "a submit blocked on the scheduler's dispatch lock",
+    "pipeline.lock_wait": "a submit, flush or dispatch blocked on the pipeline's lock",
+    "scheduler.lock_wait": "a submit blocked on the scheduler's lock",
     "dispatch.replica": "one batch attempt on one backend replica",
-    "engine.wave": "one continuous-batching serve call, admission to last retirement",
+    "engine.wave": "a caller driving the shared continuous-batching step loop until its own sequences retire",
+    "engine.join": "a caller waiting on another caller's step loop for its own sequences",
     "engine.tokenize": "encoding a wave's prompts",
     "engine.admit": "admitting queued sequences into free slots",
     "engine.prefill_step": "host side of a chunked-prefill step: inputs and dispatch",

@@ -6,12 +6,14 @@ import copy
 
 import numpy as np
 import pytest
+from _loop_join import join_mid_loop
 
 from repro.inference import tokenizer as tok
 from repro.inference.api import make_engine_client
 from repro.inference.backend import (COMPLETE, SCORE, EngineFailure, Request,
                                      Result)
-from repro.inference.continuous import ContinuousBatcher, _Seq, supports
+from repro.inference.continuous import (ContinuousBatcher, _Caller, _Seq,
+                                        supports)
 from repro.inference.engine import JaxInferenceEngine
 from repro.inference.paged_kv import OutOfBlocks, PagedKVCache
 from repro.configs import base as cfgs
@@ -188,15 +190,17 @@ def test_parity_through_client_eager_and_pipelined():
 def test_eos_retires_before_max_tokens(cont_engine):
     b = ContinuousBatcher(cont_engine, block_size=16)
     blocks = b.kv.alloc(1)
+    caller = _Caller(1, t0=0.0)
     seq = _Seq(req=Request("x", "proxy-8b", COMPLETE, max_tokens=64,
                            request_id=1),
                index=0, enc=[tok.BOS_ID, 5, 6], slot=0, blocks=blocks,
-               state="decode", cur=tok.EOS_ID)
+               state="decode", cur=tok.EOS_ID, caller=caller)
     active = [seq] + [None] * (b.slots - 1)
-    results = [None]
+    results = caller.results
     free_before = b.kv.free_count
-    b._consume(seq, active, results, t0=0.0)
+    b._consume(seq, active)
     assert results[0] is not None and results[0].tokens_out == 1
+    assert caller.left == 0                        # its caller is woken
     assert active[0] is None                       # slot freed
     assert b.retired_eos == 1
     assert b.kv.free_count == free_before + 1      # blocks recycled
@@ -225,6 +229,126 @@ def test_supported_arch_defaults_to_continuous(cont_engine):
     assert supports(cont_engine.cfg)
     eng = JaxInferenceEngine("proxy-8b", smoke=True, backend="auto")
     assert eng.backend == "continuous"
+
+
+# ---------------------------------------------------------------------------
+# shared step loop: concurrent callers
+# ---------------------------------------------------------------------------
+
+
+def _same_rows(got, want):
+    """Text, token counts and credits exact; SCOREs to float32 rounding."""
+    assert [r[:3] + r[4:] for r in got] == [r[:3] + r[4:] for r in want]
+    for g, w in zip(got, want):
+        assert g[3] == pytest.approx(w[3], rel=0, abs=1e-6)
+
+
+def _longer_set():
+    reqs = [Request("w" * (5 + 9 * i) + f" joining case {i}", "proxy-8b",
+                    COMPLETE, max_tokens=mt, request_id=i + 1)
+            for i, mt in enumerate([12, 3, 30, 5])]
+    reqs += [Request(f"does this joining row {i} pass?" + "!" * (13 * i),
+                     "proxy-8b", SCORE, request_id=10 + i) for i in range(4)]
+    return reqs
+
+
+def test_concurrent_callers_match_serving_alone(cont_engine):
+    first, second = _ragged_workload()[:7], _longer_set()
+    a, b, _ = join_mid_loop(cont_engine, first, second)
+    _same_rows([_row(r) for r in a], _serve(cont_engine, first))
+    _same_rows([_row(r) for r in b], _serve(cont_engine, second))
+    assert [r.request_id for r in b] == [r.request_id for r in second]
+
+
+def test_driver_hands_off_once_its_own_sequences_retire(cont_engine):
+    # the driver's two SCOREs retire after one prefill step; the joiner's
+    # 40-token COMPLETE is still decoding when the driver returns, so the
+    # joiner takes the loop over and finishes it
+    first = [Request(f"short score {i}", "proxy-8b", SCORE,
+                     request_id=i + 1) for i in range(2)]
+    second = [_ragged_workload()[0]]
+    a, b, left_behind = join_mid_loop(cont_engine, first, second)
+    assert left_behind == 1                 # the joiner's, still live
+    assert b[0].tokens_out == second[0].max_tokens
+    _same_rows([_row(r) for r in a], _serve(cont_engine, first))
+    _same_rows([_row(r) for r in b], _serve(cont_engine, second))
+    kv = cont_engine._batcher.kv
+    assert kv.free_count == kv.num_blocks - 1
+
+
+def test_oversized_request_fails_only_its_caller(cont_engine):
+    b = cont_engine._batcher
+    need = (b.kv.max_seq_blocks + 1) * b.block_size
+    bad = [Request("p", "proxy-8b", COMPLETE, max_tokens=need,
+                   request_id=1),
+           Request("a fine one", "proxy-8b", SCORE, request_id=2)]
+    first = _ragged_workload()[:6]
+    a, err, _ = join_mid_loop(cont_engine, first, bad)
+    assert isinstance(err, EngineFailure)
+    _same_rows([_row(r) for r in a], _serve(cont_engine, first))
+    assert b._callers == 0 and not b._pending
+
+
+def test_step_failure_fails_every_caller(cont_engine):
+    # a step that raises under the driver must not strand the joiner:
+    # it fails too, and the batcher is left empty for the next caller
+    b = cont_engine._batcher
+
+    def broken(active):
+        raise RuntimeError("device lost")
+
+    b._decode_step = broken
+    try:
+        a, err, _ = join_mid_loop(cont_engine, _ragged_workload()[:2],
+                                  _ragged_workload()[2:4])
+    finally:
+        del b._decode_step
+    assert isinstance(a, RuntimeError)       # the driver's own error
+    assert isinstance(err, EngineFailure)
+    assert b._callers == 0 and not b._pending
+    assert all(s is None for s in b._active)
+    assert b.kv.free_count == b.kv.num_blocks - 1
+    reqs = _ragged_workload()[:3]
+    assert _serve(cont_engine, reqs) == _serve(cont_engine, reqs)
+
+
+def test_joined_counts_mixed_admissions(cont_engine):
+    b = cont_engine._batcher
+    before = b.stats()
+    _serve(cont_engine, _ragged_workload())
+    lone = b.stats()
+    assert lone["joined"] == before["joined"]
+    first, second = _ragged_workload()[:7], _longer_set()
+    join_mid_loop(cont_engine, first, second)
+    after = b.stats()
+    # the driver's seven fill the slots alone; the joiner's first is
+    # admitted beside them, and none after the driver's last retired
+    # counts
+    assert after["admitted"] - lone["admitted"] == len(first) + len(second)
+    assert 1 <= after["joined"] - lone["joined"] <= len(second)
+
+
+@pytest.mark.parametrize("workload,steps", [
+    ("ragged", (6, 39, 26, 75)),
+    ("midstream", (10, 32, 48, 153)),
+])
+def test_lone_caller_runs_the_same_steps(cont_engine, workload, steps):
+    """A caller alone runs the steps the one-caller loop ran (counts
+    measured on that loop): prefill steps, decode steps, prefilling rows
+    and decoding slots."""
+    if workload == "ragged":
+        reqs = _ragged_workload()
+    else:
+        reqs = [Request(f"queued request number {i} says hello", "proxy-8b",
+                        COMPLETE, max_tokens=24 if i % 5 == 0 else 3,
+                        request_id=i + 1)
+                for i in range(3 * cont_engine.max_batch)]
+    keys = ("prefill_steps", "decode_steps", "prefill_rows", "decode_tokens")
+    before = cont_engine.backend_stats()
+    _serve(cont_engine, reqs)
+    after = cont_engine.backend_stats()
+    assert tuple(after[k] - before[k] for k in keys) == steps
+    assert after["joined"] == before["joined"]
 
 
 # ---------------------------------------------------------------------------

@@ -1,5 +1,7 @@
 """Semantic-operator runtime: RequestPipeline coalescing / dedup / flush,
 load-aware scheduling, and eager-vs-pipelined end-to-end equivalence."""
+import threading
+
 import numpy as np
 import pytest
 
@@ -86,6 +88,60 @@ def test_dedup_inflight_and_memo_cache():
     assert pipe.stats.cache_hits == 1
     assert pipe.stats.dedup_hits == 2
     assert sched.submits == 1
+
+
+class _Gated(SimulatedBackend):
+    """A simulated backend whose batches wait for ``gate``; ``entered`` is
+    set once a batch reached it."""
+
+    def __init__(self, **kw):
+        super().__init__(**kw)
+        self.entered = threading.Event()
+        self.gate = threading.Event()
+
+    def submit_batch(self, requests):
+        self.entered.set()
+        assert self.gate.wait(30)
+        return super().submit_batch(requests)
+
+
+def _mid_dispatch():
+    """A pipeline whose one queued request is being dispatched, held in
+    the backend, by another thread; returns the pieces and that thread."""
+    sched = Scheduler()
+    backend = _Gated(seed=0)
+    sched.register(backend)
+    pipe = RequestPipeline(sched, PipelineConfig())
+    f1 = pipe.submit(Request("same prompt", "proxy-8b", SCORE))
+    flusher = threading.Thread(target=pipe.flush)
+    flusher.start()
+    assert backend.entered.wait(30)
+    return sched, backend, pipe, f1, flusher
+
+
+def test_duplicate_mid_dispatch_attaches_and_resolves():
+    sched, backend, pipe, f1, flusher = _mid_dispatch()
+    # the dispatch holds no lock: a duplicate arriving now attaches
+    f2 = pipe.submit(Request("same prompt", "proxy-8b", SCORE))
+    assert pipe.stats.inflight_hits == 1 and not f2.done()
+    backend.gate.set()
+    flusher.join(30)
+    assert f2.result().score == f1.result().score
+    assert sched.submits == 1 and pipe.stats.dispatched == 1
+
+
+def test_result_waits_for_another_threads_dispatch():
+    sched, backend, pipe, f1, flusher = _mid_dispatch()
+    got = []
+    waiter = threading.Thread(target=lambda: got.append(f1.result()))
+    waiter.start()
+    waiter.join(0.2)
+    assert waiter.is_alive() and not got   # blocked, not failed
+    backend.gate.set()
+    waiter.join(30)
+    flusher.join(30)
+    assert got and got[0] is f1.result()
+    assert sched.submits == 1              # the waiter dispatched nothing
 
 
 def test_lru_hot_key_survives_cache_pressure():
