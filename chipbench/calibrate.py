@@ -3,9 +3,10 @@
     python3 chipbench/calibrate.py --workload <cell> --seconds <s> --seeds 1 2 3 ...
 
 One process runs the cell once per seed (engine, warm-up, a window at the
-cell's own load, the check), and beside each check the lower-precision controls of
-``reference/qwen3.py`` on the same prompts and tokens (int8 and fp8).  One JSON line per
-seed on standard output, and all of them in
+cell's own load, the check), and beside each check the lower-precision
+controls of the cell's float32 reference (``reference/common.py``) on the
+same prompts and tokens (int8 and fp8).  One JSON line per seed on
+standard output, and all of them in
 ``chiprun_out/calibrate_<cell>.jsonl``: the program's numbers, each
 control's, whether the run and each control came out correct under the
 limits in force, and every sampled gap of each stream.  Exits 1 where a

@@ -11,11 +11,11 @@ fails):
   row per input row.  Exact: its limit is 0.
 * ``window_compiles`` -- programs compiled or loaded from the compile
   cache inside the measured window.  Always compared, limit 0.
-* against the float32 reference (``reference/qwen3.py``), over a sample
-  of the served requests drawn from ``--seed`` with the longest of each
-  kind in it, the widest gap (``<gap>``) and, for a kind the limits file
-  gives a ``tolerance``, the share of the sample whose gap exceeds it
-  (``<gap>_share``):
+* against the float32 reference of the configuration's architecture
+  (``reference/<model_type>.py``), over a sample of the served requests
+  drawn from ``--seed`` with the longest of each kind in it, the widest
+  gap (``<gap>``) and, for a kind the limits file gives a ``tolerance``,
+  the share of the sample whose gap exceeds it (``<gap>_share``):
   - ``score_gap``: between a served SCORE's logit, log(s / (1 - s)),
     and the reference's logit(yes) - logit(no) after the same prompt;
   - ``token_gap``: by which the reference's logit of a served (greedy)
@@ -25,7 +25,8 @@ fails):
     label after an AI_CLASSIFY prompt as the engine scored it and as the
     reference scores it.
 
-A control is a lower-precision stream of the reference (int8 or fp8),
+A control is a lower-precision stream of the reference (int8 or fp8,
+``reference/common.py``, the same code for every architecture),
 put in the program's place on the same prompts and tokens: its numbers
 read the same gaps for what it would have served, and the same limits
 judge it.  A limit is sound only where every control fails it.
@@ -41,7 +42,7 @@ from typing import Dict
 import numpy as np
 
 from chipbench import tokenizer
-from chipbench.reference import qwen3
+from chipbench.reference.common import Probe
 
 KINDS = ("score", "complete", "classify")
 
@@ -124,22 +125,22 @@ def probes(sample, max_seq: int):
     for k, (req, res, toks) in enumerate(sample):
         if req.kind == "score":
             ids = tokenizer.encode(req.prompt, max_len=max_seq)
-            out.append(qwen3.Probe(ids, [len(ids) - 1], [tokenizer.YES_ID]))
+            out.append(Probe(ids, [len(ids) - 1], [tokenizer.YES_ID]))
             owner.append((k, None))
         elif req.kind == "complete":
             ids = tokenizer.encode(req.prompt, max_len=max_seq)
             toks = list(toks)
-            out.append(qwen3.Probe(ids + toks[:-1],
-                                   [len(ids) - 1 + j for j in range(len(toks))],
-                                   toks))
+            out.append(Probe(ids + toks[:-1],
+                             [len(ids) - 1 + j for j in range(len(toks))],
+                             toks))
             owner.append((k, None))
         else:
             pe = tokenizer.encode(req.prompt + tokenizer.CLASSIFY_SUFFIX,
                                   max_len=max_seq // 2)
             for lb in req.labels:
                 ce = tokenizer.encode(lb, bos=False)
-                out.append(qwen3.Probe(pe + ce, [len(pe) - 1 + j
-                                                 for j in range(len(ce))], ce))
+                out.append(Probe(pe + ce, [len(pe) - 1 + j
+                                           for j in range(len(ce))], ce))
                 owner.append((k, lb))
     return out, owner
 
@@ -224,8 +225,9 @@ def check(cell, seed: int, records, served, *, window_compiles: int = 0,
     if sample:
         t0 = time.perf_counter()
         probe_list, owner = probes(sample, cell.max_seq)
-        reads = qwen3.run(cell.conf, seed, probe_list, yes=tokenizer.YES_ID,
-                          no=tokenizer.NO_ID, controls=controls)
+        reads = cell.arch.run(cell.conf, seed, probe_list,
+                              yes=tokenizer.YES_ID, no=tokenizer.NO_ID,
+                              controls=controls)
         per = gaps(sample, owner, reads, controls)
         print(f"[chipbench] reference over {len(probe_list)} sequences "
               f"({sum(len(p.tokens) for p in probe_list)} tokens): "

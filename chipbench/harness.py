@@ -1,11 +1,13 @@
 """One run of one cell: build, warm up, measure, check, report.
 
 Everything a cell is made of is found by name from ``BENCHMARK.json``:
-its configuration (``configs/<config>.json``), its traffic mix
+its configuration (``configs/<config>.json``) and the architecture module
+its ``model_type`` names (``reference/<model_type>.py``: the program's
+model config, the counts and the float32 reference), its traffic mix
 (``traffic/<mix>.json``, read by ``generator.py``), its check's sample
 and limits (``limits/<workload>.json``) and one reader per per-layer
-metric (``metrics/<metric>.py``).  Adding a cell, a configuration or a
-metric adds files and entries; nothing here changes.
+metric (``metrics/<metric>.py``).  Adding a cell, a configuration, an
+architecture or a metric adds files and entries; nothing here changes.
 
 The system under test is the program's own serving path: a
 ``JaxInferenceEngine`` built as ``make_engine_client`` builds one (the
@@ -29,6 +31,7 @@ import gc
 import importlib.util
 import json
 import math
+import re
 import shutil
 import sys
 import tempfile
@@ -42,7 +45,6 @@ import numpy as np
 
 from chipbench import flops, generator, tokenizer, tracereduce
 from chipbench.peaks import peaks
-from chipbench.reference.qwen3 import Shape
 
 BENCH = Path(__file__).resolve().parent
 ROOT = BENCH.parent
@@ -65,6 +67,7 @@ class Cell:
     end_to_end: List[dict]
     per_layer: List[dict]
     chips: int
+    arch: Any                       # reference/<model_type>.py, loaded
 
     @property
     def max_seq(self) -> int:
@@ -88,24 +91,30 @@ def load_cell(workload: str, root: Path = ROOT) -> Cell:
     per_layer = [m for m in spec["per_layer"]
                  if workload in m.get("workloads", ())]
     return Cell(workload, cell["config"], conf, mix, check, e2e, per_layer,
-                int(cell["chips"]))
+                int(cell["chips"]), load_arch(conf["model_type"], root))
 
 
-def model_config(conf: dict, name: str):
-    """The program's ModelConfig for a Qwen3 ``config.json``."""
-    from repro.configs.base import ATTN, ModelConfig
-    if conf["model_type"] != "qwen3" or conf["hidden_act"] != "silu":
-        raise ValueError(f"{name}: not a Qwen3 configuration")
-    return ModelConfig(
-        name=name, family="dense", num_layers=conf["num_hidden_layers"],
-        d_model=conf["hidden_size"], num_heads=conf["num_attention_heads"],
-        num_kv_heads=conf["num_key_value_heads"], head_dim=conf["head_dim"],
-        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
-        qk_norm=True, use_bias=conf["attention_bias"],
-        rope_theta=float(conf["rope_theta"]),
-        norm_eps=float(conf["rms_norm_eps"]),
-        tie_embeddings=conf["tie_word_embeddings"], period=(ATTN,),
-        dtype=conf["torch_dtype"])
+def load_arch(model_type: str, root: Path = ROOT):
+    """The architecture module of a configuration's ``model_type``,
+    ``chipbench/reference/<model_type>.py`` under ``root``, loaded by path.
+    It gives the program's ``model_config(conf, name)``, the sizes and
+    counts ``Shape.of(conf)``, and the float32 reference ``run``."""
+    if not re.fullmatch(r"\w[\w-]*", model_type):
+        raise ValueError(f"model_type {model_type!r} names no module")
+    path = root / "chipbench" / "reference" / f"{model_type}.py"
+    if not path.is_file():
+        raise FileNotFoundError(
+            f"model_type {model_type!r}: no architecture module {path}")
+    return load_module("chipbench.reference." + model_type, path)
+
+
+def load_module(name: str, path: Path):
+    """The Python file at ``path``, loaded as module ``name``."""
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    sys.modules[name] = mod             # dataclasses look their module up
+    spec.loader.exec_module(mod)
+    return mod
 
 
 # ---------------------------------------------------------------------------
@@ -218,12 +227,13 @@ class CompileMeter:
 # ---------------------------------------------------------------------------
 
 
-def build_engine(conf: dict, name: str, seed: int, device):
-    """The engine as ``make_engine_client`` builds one, at the
-    configuration's ``max_seq``, weights drawn from ``seed`` on device."""
+def build_engine(arch, conf: dict, name: str, seed: int, device):
+    """The engine as ``make_engine_client`` builds one, for the architecture
+    module ``arch``'s model at the configuration's ``max_seq``, weights
+    drawn from ``seed`` on device."""
     import jax
     from repro.inference.engine import JaxInferenceEngine
-    engine = JaxInferenceEngine(model_config(conf, name),
+    engine = JaxInferenceEngine(arch.model_config(conf, name),
                                 engine_id=f"{name}#0", seed=seed,
                                 device=device,
                                 max_seq=int(conf["serving"]["max_seq"]))
@@ -241,7 +251,8 @@ def build(cell: Cell, work: generator.Workload, seed: int, device):
     from repro.inference.scheduler import Scheduler
     from repro.obs import Observability
     from repro.tables.table import Table
-    engine = build_engine(cell.conf, cell.config_name, seed, device)
+    engine = build_engine(cell.arch, cell.conf, cell.config_name, seed,
+                          device)
     sched = Scheduler()
     sched.register(engine)
     catalog = Catalog({n: Table(cols) for n, cols in work.tables.items()})
@@ -444,7 +455,7 @@ def overlap(a0: float, a1: float, b0: float, b1: float) -> float:
     return max(min(a1, b1) - max(a0, b0), 0.0) / (a1 - a0)
 
 
-def request_flops(shape: Shape, req, res, max_seq: int) -> float:
+def request_flops(shape, req, res, max_seq: int) -> float:
     if req.kind == "score":
         return flops.score(shape, len(tokenizer.encode(req.prompt,
                                                        max_len=max_seq)))
@@ -463,7 +474,7 @@ def request_flops(shape: Shape, req, res, max_seq: int) -> float:
 class RunData:
     """Everything a per-layer metric reader may read."""
     cell: Cell
-    shape: Shape
+    shape: Any                               # the architecture's Shape
     peaks: dict
     t0: float
     seconds: float
@@ -509,12 +520,8 @@ class RunData:
 def load_reader(metric: str) -> Callable[[RunData], Optional[float]]:
     """``metrics/<metric>.py``'s ``read``; the file name is the metric's
     name, dots and all, so it is loaded by path."""
-    path = BENCH / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(
-        "chipbench.metrics." + metric.replace(".", "_"), path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return load_module("chipbench.metrics." + metric.replace(".", "_"),
+                       BENCH / "metrics" / f"{metric}.py").read
 
 
 # ---------------------------------------------------------------------------
@@ -615,7 +622,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool, *,
     if late:
         log(f"generator lateness: max {max(late) * 1e3:.3f} ms, p95 "
             f"{float(np.percentile(late, 95)) * 1e3:.3f} ms over {len(late)}")
-    data = RunData(cell, Shape.of(cell.conf),
+    data = RunData(cell, cell.arch.Shape.of(cell.conf),
                    chip_peaks or peaks(device.device_kind), t0,
                    seconds, records, list(recorder.dispatches),
                    list(recorder.steps), (backend0, backend1), (pipe0, pipe1),

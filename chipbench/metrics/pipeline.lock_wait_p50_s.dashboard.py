@@ -1,8 +1,10 @@
 """Median of ``QueryReport.lock_wait_s`` over the answered queries due in
-the window: the seconds each query's thread waited on the request
-pipeline's and the scheduler's dispatch locks, queued behind other
-queries' engine batches.  A program whose reports lack it reads
-nothing."""
+the window: the seconds each query's thread waited to take the request
+pipeline's and the scheduler's locks.  Those locks guard their
+bookkeeping only and are not held across an engine call, so this reads
+waits on shared state; a query that waits for the engine's slots waits in
+the batcher, which this does not see.  A program whose reports lack it
+reads nothing."""
 import numpy as np
 
 

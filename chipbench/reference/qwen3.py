@@ -1,4 +1,10 @@
-"""Plain float32 Qwen3 forward, and its lower-precision controls.
+"""Qwen3 for the benchmark: the program's configuration, the counts of
+the work a request needs, and a plain float32 forward.
+
+The harness loads this module by path for every configuration whose
+``model_type`` is ``qwen3``, and reads ``model_config``, ``Shape.of``
+(with its counts) and ``run``; what every architecture shares is in
+``common.py``.
 
 The published architecture (Qwen3 ``config.json``): token embedding;
 per layer RMSNorm -> GQA attention with per-head RMSNorm on q and k
@@ -12,15 +18,6 @@ initialiser draws them from (``jax.random`` key splits, a normal scaled by
 0.02 for the embedding and head and by 1/sqrt(fan_in) for projections,
 rounded to the served bfloat16, norms at 1), one layer at a time, so the
 reference takes nothing the program made and fits beside nothing.
-
-``controls`` adds streams beside the reference: the same forward with
-every matrix product one precision step below the served bfloat16 --
-``int8`` (weights per output channel, activations per token, symmetric;
-int32 accumulation) or ``fp8`` (float8_e4m3fn, scaled the same way).
-The check's limits are set so that a control fails them.
-
-Each sequence is padded on the right to a power-of-two length and run by
-itself; causal attention keeps padding out of every real position.
 """
 from __future__ import annotations
 
@@ -32,11 +29,36 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-HI = jax.lax.Precision.HIGHEST
+from chipbench.reference import common
+from chipbench.reference.common import HI, Probe, rms_norm, streams
+
+BF16 = 2
+
+
+def model_config(conf: dict, name: str):
+    """The program's ModelConfig for a Qwen3 ``config.json``."""
+    from repro.configs.base import ATTN, ModelConfig
+    if conf["hidden_act"] != "silu":
+        raise ValueError(f"{name}: not a Qwen3 configuration")
+    return ModelConfig(
+        name=name, family="dense", num_layers=conf["num_hidden_layers"],
+        d_model=conf["hidden_size"], num_heads=conf["num_attention_heads"],
+        num_kv_heads=conf["num_key_value_heads"], head_dim=conf["head_dim"],
+        d_ff=conf["intermediate_size"], vocab_size=conf["vocab_size"],
+        qk_norm=True, use_bias=conf["attention_bias"],
+        rope_theta=float(conf["rope_theta"]),
+        norm_eps=float(conf["rms_norm_eps"]),
+        tie_embeddings=conf["tie_word_embeddings"], period=(ATTN,),
+        dtype=conf["torch_dtype"])
 
 
 @dataclasses.dataclass(frozen=True)
 class Shape:
+    """The sizes, and the operations and bytes of the work a request needs
+    through them (``flops.py`` adds them up per request).  Each layer sees
+    every prompt and fed-back token, attends causally over the positions
+    before each token, and the head is counted only for the rows a request
+    reads."""
     layers: int
     d: int
     heads: int
@@ -49,13 +71,46 @@ class Shape:
 
     @classmethod
     def of(cls, conf: dict) -> "Shape":
-        assert conf["model_type"] == "qwen3" and conf["hidden_act"] == "silu"
+        assert conf["hidden_act"] == "silu"
         assert not conf["tie_word_embeddings"] and not conf["attention_bias"]
         return cls(conf["num_hidden_layers"], conf["hidden_size"],
                    conf["num_attention_heads"], conf["num_key_value_heads"],
                    conf["head_dim"], conf["intermediate_size"],
                    conf["vocab_size"], float(conf["rms_norm_eps"]),
                    float(conf["rope_theta"]))
+
+    def dense_per_token(self) -> float:
+        """Projection and MLP FLOPs of one token through one layer."""
+        q, kv = self.heads * self.head_dim, self.kv_heads * self.head_dim
+        return (2.0 * self.d * (q + 2 * kv) + 2.0 * q * self.d
+                + 6.0 * self.d * self.d_ff)
+
+    def attention(self, start: int, n: int) -> float:
+        """Score and value FLOPs of tokens at positions start..start+n-1 of
+        one layer, each attending to every position up to its own."""
+        keys = n * start + n * (n + 1) / 2.0
+        return 4.0 * self.heads * self.head_dim * keys
+
+    def extend(self, start: int, n: int) -> float:
+        """FLOPs of n tokens after ``start`` cached ones, through every
+        layer."""
+        return self.layers * (n * self.dense_per_token()
+                              + self.attention(start, n))
+
+    def head_rows(self, rows: int) -> float:
+        """FLOPs of ``rows`` rows of the head (one vocabulary entry's logit
+        at one position each)."""
+        return 2.0 * self.d * rows
+
+    def decode_attention(self, length: int):
+        """(FLOPs, bytes) of one flash-decode call for one sequence whose
+        cache holds ``length`` valid positions, over every layer: q.k and
+        p.v over the valid keys, and the bfloat16 K and V those keys
+        need."""
+        flops = 4.0 * self.heads * self.head_dim * length * self.layers
+        nbytes = (2.0 * length * self.kv_heads * self.head_dim * BF16
+                  * self.layers)
+        return flops, nbytes
 
 
 # ---------------------------------------------------------------------------
@@ -97,10 +152,6 @@ def make_layer(key, s: Shape) -> Dict[str, jax.Array]:
 # ---------------------------------------------------------------------------
 
 
-def rms_norm(x, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps)
-
-
 def rope(x, theta):
     """x: [S, H, hd], half-split rotation at positions 0..S-1."""
     S, _, hd = x.shape
@@ -110,48 +161,6 @@ def rope(x, theta):
     cos, sin = jnp.cos(ang)[:, None], jnp.sin(ang)[:, None]
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate([x1 * cos - x2 * sin, x2 * cos + x1 * sin], -1)
-
-
-def quantize(x, axis):
-    """Symmetric int8 along ``axis`` (the contracted one); returns (q, scale)."""
-    scale = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / 127.0
-    scale = jnp.where(scale == 0, 1.0, scale)
-    return jnp.clip(jnp.round(x / scale), -127, 127).astype(jnp.int8), scale
-
-
-def mm_f32(x, w):
-    return jnp.matmul(x, w.astype(jnp.float32), precision=HI)
-
-
-def mm_int8(x, wq):
-    """x: [S, k] float32; wq: (int8 [k, n], scale [1, n])."""
-    q, ws = wq
-    xq, xs = quantize(x, axis=-1)
-    acc = jax.lax.dot_general(xq, q, (((1,), (0,)), ((), ())),
-                              preferred_element_type=jnp.int32)
-    return acc.astype(jnp.float32) * xs * ws
-
-
-FP8_MAX = 448.0          # largest finite float8_e4m3fn
-
-
-def to_fp8(x, axis):
-    """Scaled to float8_e4m3fn along ``axis`` and back: (values, scale)."""
-    scale = jnp.max(jnp.abs(x), axis=axis, keepdims=True) / FP8_MAX
-    scale = jnp.where(scale == 0, 1.0, scale)
-    return (x / scale).astype(jnp.float8_e4m3fn).astype(jnp.float32), scale
-
-
-def mm_fp8(x, wq):
-    """One bfloat16 pass: float8_e4m3fn values (4 significant bits) are
-    bfloat16 values, so the products are exact and sum in float32."""
-    q, ws = wq
-    xq, xs = to_fp8(x, axis=-1)
-    return jnp.matmul(xq, q, precision=jax.lax.Precision.DEFAULT) * xs * ws
-
-
-LOWER = {"int8": (lambda w: quantize(w, axis=0), mm_int8),
-         "fp8": (lambda w: to_fp8(w, axis=0), mm_fp8)}
 
 
 def _layer(x, w, s: Shape, mm):
@@ -176,54 +185,7 @@ def _layer(x, w, s: Shape, mm):
 
 def _apply_layer(w, xs, s: Shape, controls: tuple):
     """xs: {"ref": [S, d], <control>: [S, d], ...}, one sequence."""
-    with jax.default_matmul_precision("highest"):
-        out = {"ref": _layer(xs["ref"], w, s, mm_f32)}
-        for name in controls:
-            lower, mm = LOWER[name]
-            wq = {k: lower(v.astype(jnp.float32)) for k, v in w.items()}
-            out[name] = _layer(xs[name], wq, s, mm)
-        return out
-
-
-def _readout(head, hs, tok, s: Shape, yes: int, no: int, controls: tuple):
-    """Statistics of the logits at gathered positions: hs[stream] [P, d]."""
-    with jax.default_matmul_precision("highest"):
-        r = mm_f32(rms_norm(hs["ref"], s.eps), head)
-        out = {"ref_t": jnp.take_along_axis(r, tok[:, None], 1)[:, 0],
-               "ref_max": jnp.max(r, -1),
-               "ref_lse": jax.nn.logsumexp(r, -1),
-               "ref_yes": r[:, yes], "ref_no": r[:, no]}
-        for name in controls:
-            lower, mm = LOWER[name]
-            c = mm(rms_norm(hs[name], s.eps), lower(head.astype(jnp.float32)))
-            pick = jnp.argmax(c, -1)
-            out.update({
-                f"{name}_pick_ref": jnp.take_along_axis(
-                    r, pick[:, None], 1)[:, 0],
-                f"{name}_t": jnp.take_along_axis(c, tok[:, None], 1)[:, 0],
-                f"{name}_lse": jax.nn.logsumexp(c, -1),
-                f"{name}_yes": c[:, yes], f"{name}_no": c[:, no]})
-        return out
-
-
-@dataclasses.dataclass
-class Probe:
-    """Read the logits at ``positions`` of ``tokens``; ``targets`` are the
-    ids whose logits are read there (a served or label token)."""
-    tokens: List[int]
-    positions: List[int]
-    targets: List[int]
-
-
-def _bucket(n: int, lo: int = 64) -> int:
-    b = lo
-    while b < n:
-        b *= 2
-    return b
-
-
-def _rows(x, positions):
-    return x[positions]
+    return streams(lambda x, w, mm: _layer(x, w, s, mm), w, xs, controls)
 
 
 def run(conf: dict, seed: int, probes: Sequence[Probe], *, yes: int, no: int,
@@ -239,51 +201,18 @@ def run(conf: dict, seed: int, probes: Sequence[Probe], *, yes: int, no: int,
     controls = tuple(controls)
     make_layer_j = jax.jit(make_layer, static_argnums=1)
     apply_j = jax.jit(_apply_layer, static_argnums=(2, 3))
-    readout_j = jax.jit(_readout, static_argnums=(3, 4, 5, 6))
-    rows_j = jax.jit(_rows)
     embed = jax.jit(lambda k: _normal(k, (s.vocab, s.d), 0.02))(
         top_keys(seed)[0])
-    take_j = jax.jit(lambda e, ids: e[ids].astype(jnp.float32))
-    hidden = []
-    for p in probes:
-        ids = np.zeros((_bucket(len(p.tokens)),), np.int32)
-        ids[:len(p.tokens)] = p.tokens
-        x = take_j(embed, ids)
-        hidden.append({name: x for name in ("ref",) + controls})
+    hidden = common.inputs(embed, probes, controls)
     del embed
     keys = layer_keys(seed, s.layers)
     for layer in range(s.layers):
         w = make_layer_j(keys[layer], s)
         hidden = [apply_j(w, x, s, controls) for x in hidden]
         del w
+    picked = common.gather(hidden, probes)
+    del hidden
     head = jax.jit(lambda k: _normal(k, (s.d, s.vocab), 0.02))(
         top_keys(seed)[1])
-    # each probe's positions, gathered and padded to a power of two
-    picked = {name: [] for name in ("ref",) + controls}
-    toks, owner = [], []
-    for i, (p, x) in enumerate(zip(probes, hidden)):
-        pos = np.zeros((_bucket(len(p.positions), lo=1),), np.int32)
-        pos[:len(p.positions)] = p.positions
-        for name in picked:
-            picked[name].append(
-                np.asarray(rows_j(x[name], pos))[:len(p.positions)])
-        toks += list(p.targets)
-        owner += [i] * len(p.positions)
-    del hidden
-    picked = {k: np.concatenate(v) for k, v in picked.items()}
-    got: Dict[str, List[np.ndarray]] = {}
-    for a in range(0, len(toks), max_rows):
-        n = min(max_rows, len(toks) - a)
-        rows = {k: np.zeros((max_rows, s.d), np.float32)
-                for k in picked}
-        for k, v in picked.items():
-            rows[k][:n] = v[a:a + n]
-        tk = np.zeros((max_rows,), np.int32)
-        tk[:n] = toks[a:a + n]
-        part = readout_j(head, rows, tk, s, yes, no, controls)
-        for k, v in part.items():
-            got.setdefault(k, []).append(np.asarray(v, np.float64)[:n])
-    flat = {k: np.concatenate(v) for k, v in got.items()}
-    owner = np.asarray(owner)
-    return [{k: v[owner == i] for k, v in flat.items()}
-            for i in range(len(probes))]
+    return common.read_out(head, picked, probes, eps=s.eps, yes=yes, no=no,
+                           controls=controls, max_rows=max_rows)

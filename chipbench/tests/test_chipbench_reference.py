@@ -46,7 +46,8 @@ def served():
     prompts = ["".join(chr(97 + c) for c in rng.integers(0, 26, n))
                for n in (5, 31, 40, 97, 150, 230)]
     for seed in SEEDS:
-        engine = harness.build_engine(conf, "tiny", seed, jax.devices()[0])
+        engine = harness.build_engine(qwen3, conf, "tiny", seed,
+                                      jax.devices()[0])
         rec = harness.Recorder(engine)
         reqs = [Request(p, "tiny", SCORE) for p in prompts] + [
             Request(p, "tiny", COMPLETE, max_tokens=12) for p in prompts]

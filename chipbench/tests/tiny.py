@@ -1,6 +1,7 @@
 """A checkout-shaped directory whose cells run the real harness on the CPU
-at a size a test can hold: the benchmark's own cells and traffic, with
-each Qwen3 configuration cut to two layers of width 64."""
+at a size a test can hold: the benchmark's own cells, traffic and
+architecture modules, with each Qwen3 configuration cut to two layers of
+width 64."""
 from __future__ import annotations
 
 import json
@@ -26,6 +27,8 @@ def make_root(path: Path, limits: dict) -> Path:
     (path / "chipbench/configs").mkdir(parents=True)
     (path / "chipbench/limits").mkdir()
     shutil.copytree(REPO / "chipbench/traffic", path / "chipbench/traffic")
+    shutil.copytree(REPO / "chipbench/reference", path / "chipbench/reference",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     (path / "chipbench/configs/tiny.json").write_text(json.dumps(conf()))
     for c in spec["configs"]:
         c["file"] = "chipbench/configs/tiny.json"
