@@ -27,7 +27,14 @@ class MoEConfig:
     shared_d_ff: int = 0            # hidden dim of the fused shared expert
     router_aux_loss: float = 0.001  # load-balance loss weight
     capacity_factor: float = 1.25   # per-expert token capacity multiplier
+                                    # (train only: inference is dropless)
     padded_num_experts: int = 0     # experts padded up for even EP sharding
+    # --- router (defaults: softmax over experts, top-k renormalized) ------
+    score_func: str = "softmax"     # softmax | sigmoid (per-expert scores)
+    selection_bias: bool = False    # per-expert bias added for top-k
+                                    # selection only, never to the gates
+    route_scale: float = 1.0        # gates (selected scores over their
+                                    # sum) multiplied by this
 
     def __post_init__(self):
         if self.padded_num_experts == 0:
@@ -50,6 +57,9 @@ class ModelConfig:
     use_bias: bool = False
     rope_theta: float = 10000.0
     attention_window: int = 0       # sliding window size for LOCAL_ATTN
+    attn_out_gate: bool = False     # o = attn(x) * sigmoid(x Wg), then Wo
+    rope_local_only: bool = False   # RoPE on LOCAL_ATTN layers only; global
+                                    # layers use no positional encoding
     mrope_sections: Tuple[int, ...] = ()   # M-RoPE (qwen2-vl): rotary dims per (t,h,w)
     # --- norms / embeddings ---------------------------------------------
     use_rope: bool = True
@@ -61,9 +71,13 @@ class ModelConfig:
     tie_embeddings: bool = False
     scale_embedding: bool = False   # multiply embeddings by sqrt(d_model) (gemma)
     logit_softcap: float = 0.0
+    sandwich_norm: bool = False     # also norm each sublayer's output:
+                                    # h + N2(attn(N1 h)), h + N4(ffn(N3 h))
     # --- block pattern ----------------------------------------------------
-    # The model is `num_periods` repetitions of `period` followed by `tail`.
-    # Homogeneous models: period=("attn",), num_periods=num_layers, tail=().
+    # The model is `lead`, then `num_periods` repetitions of `period`, then
+    # `tail`.  Homogeneous models: period=("attn",), num_periods=num_layers.
+    # `lead` layers carry a dense MLP of width d_ff even in an MoE model.
+    lead: Tuple[str, ...] = ()
     period: Tuple[str, ...] = (ATTN,)
     tail: Tuple[str, ...] = ()
     # --- MoE ---------------------------------------------------------------
@@ -95,15 +109,15 @@ class ModelConfig:
     # ---- derived ----
     @property
     def num_periods(self) -> int:
-        body = self.num_layers - len(self.tail)
+        body = self.num_layers - len(self.lead) - len(self.tail)
         assert body % len(self.period) == 0, (
             f"{self.name}: {self.num_layers} layers does not decompose into "
-            f"{self.period} * k + {self.tail}")
+            f"{self.lead} + {self.period} * k + {self.tail}")
         return body // len(self.period)
 
     @property
     def block_pattern(self) -> Tuple[str, ...]:
-        return self.period * self.num_periods + self.tail
+        return self.lead + self.period * self.num_periods + self.tail
 
     @property
     def q_dim(self) -> int:
@@ -130,8 +144,8 @@ class ModelConfig:
         if not self.tie_embeddings:
             n += d * v                                # lm_head
         n += d                                        # final norm
-        for blk in self.block_pattern:
-            n += self._block_params(blk)
+        for i, blk in enumerate(self.block_pattern):
+            n += self._block_params(blk, dense=i < len(self.lead))
         if self.is_encoder_decoder:
             n += self.encoder_layers * self._block_params(ATTN)
             # cross attention per decoder layer
@@ -139,14 +153,16 @@ class ModelConfig:
             n += d                                    # encoder final norm
         return n
 
-    def _block_params(self, blk: str) -> int:
+    def _block_params(self, blk: str, dense: bool = False) -> int:
         d = self.d_model
-        n = 2 * d                                     # two pre-norms
+        n = (4 if self.sandwich_norm else 2) * d      # pre (and post) norms
         if blk in (ATTN, LOCAL_ATTN):
             n += d * self.q_dim + 2 * d * self.kv_dim + self.q_dim * d
+            if self.attn_out_gate:
+                n += d * self.q_dim
             if self.qk_norm:
                 n += 2 * self.head_dim
-            n += self._mlp_params()
+            n += self._mlp_params(dense)
         elif blk == RGLRU:
             w = self.lru_width
             n += 2 * d * w + w * d                    # x/gate in-proj, out-proj
@@ -163,11 +179,13 @@ class ModelConfig:
             raise ValueError(blk)
         return n
 
-    def _mlp_params(self) -> int:
+    def _mlp_params(self, dense: bool = False) -> int:
         d = self.d_model
-        if self.moe is not None:
+        if self.moe is not None and not dense:
             m = self.moe
             n = d * m.num_experts                     # router
+            if m.selection_bias:
+                n += m.num_experts
             n += m.num_experts * (3 * d * m.expert_d_ff)
             if m.num_shared_experts:
                 n += 3 * d * m.shared_d_ff
@@ -181,7 +199,8 @@ class ModelConfig:
         m = self.moe
         full_expert = 3 * self.d_model * m.expert_d_ff
         inactive = (m.num_experts - m.num_experts_per_tok) * full_expert
-        n_moe_layers = sum(1 for b in self.block_pattern if b in (ATTN, LOCAL_ATTN))
+        n_moe_layers = sum(1 for b in self.block_pattern[len(self.lead):]
+                           if b in (ATTN, LOCAL_ATTN))
         return self.param_count() - inactive * n_moe_layers
 
 
@@ -217,7 +236,7 @@ ARCH_IDS = (
 )
 
 # extra configs used by the paper reproduction (cascade proxy/oracle pair)
-EXTRA_IDS = ("proxy-8b", "oracle-70b")
+EXTRA_IDS = ("proxy-8b", "oracle-70b", "trinity-mini")
 
 _MODULE_FOR = {
     "recurrentgemma-9b": "recurrentgemma_9b",
@@ -232,6 +251,7 @@ _MODULE_FOR = {
     "rwkv6-1.6b": "rwkv6_16b",
     "proxy-8b": "proxy_8b",
     "oracle-70b": "oracle_70b",
+    "trinity-mini": "trinity_mini",
 }
 
 

@@ -57,6 +57,15 @@ the wall seconds the loop ran, counted once whoever drove it
 sequences admitted while another caller's were live or pending
 (``joined``).  Nothing here synchronizes with the device beyond the
 readbacks the loop needs anyway.
+
+Sliding-window layers page like global ones: each keeps a full per-layer
+paged cache, and its attention masks the positions before the window
+(``attention.decode_attention``, ``flash_decode``'s ``window``).  So a
+step gathers, for a windowed layer, blocks it then masks away:
+``window_blocks`` counts the KV blocks each step gathered for its
+windowed layers, over the rows it advanced and those layers, and
+``window_blocks_outside`` those of them wholly before the row's window
+(before the first position the row's earliest new token sees).
 """
 from __future__ import annotations
 
@@ -79,13 +88,14 @@ from repro.obs.trace import active_tracer
 
 
 def supports(cfg) -> bool:
-    """Continuous batching serves pure global-attention decoders: every
-    block's KV cache must be a flat per-layer [B, Smax] tensor for the
-    paged pool to tile (ring buffers, recurrent states and encoder caches
-    fall back to the static path)."""
+    """Continuous batching serves attention decoders, global and
+    sliding-window layers alike: every block's KV cache must be a flat
+    per-layer [B, Smax] tensor for the paged pool to tile (a windowed
+    layer gets one too and masks its window; recurrent states and encoder
+    caches fall back to the static path)."""
     if cfg.is_encoder_decoder or cfg.frontend != "none":
         return False
-    return all(b == cfgs.ATTN for b in cfg.block_pattern)
+    return all(b in (cfgs.ATTN, cfgs.LOCAL_ATTN) for b in cfg.block_pattern)
 
 
 def _pow2(n: int) -> int:
@@ -174,6 +184,10 @@ class ContinuousBatcher:
         self.peak_blocks = 0
         self.loop_s = 0.0          # wall seconds the step loop ran
         self.readback_s = 0.0      # of which blocked reading step outputs
+        self._windowed = sum(b == cfgs.LOCAL_ATTN
+                             for b in self.model.cfg.block_pattern)
+        self.window_blocks = 0     # gathered for windowed layers (docstring)
+        self.window_blocks_outside = 0   # of which wholly before the window
 
     # ------------------------------------------------------------------
     # serve loop
@@ -369,6 +383,18 @@ class ContinuousBatcher:
                 nb = max(nb, self.kv.blocks_for(int(self.lens_np[s.slot]) + h))
         return min(_pow2(nb), self.kv.max_seq_blocks)
 
+    def _count_window(self, seqs: Sequence[_Seq], nb: int) -> None:
+        """The window counters of one step gathering ``nb`` blocks for the
+        rows of ``seqs``, before their lengths advance."""
+        if not self._windowed:
+            return
+        W, bs = self.model.cfg.attention_window, self.block_size
+        for s in seqs:
+            first = int(self.lens_np[s.slot]) - W + 1   # first position seen
+            self.window_blocks += nb * self._windowed
+            self.window_blocks_outside += (min(max(first, 0) // bs, nb)
+                                           * self._windowed)
+
     # ------------------------------------------------------------------
     # batched chunked prefill
     # ------------------------------------------------------------------
@@ -389,6 +415,7 @@ class ContinuousBatcher:
             key = ("cb_prefill", B, C, nb, self.decode_impl)
             fn = self.engine._jit(key, self._prefill_fn, donate=(1,))
             dev = self._device_state(active, nb)
+            self._count_window(pre, nb)
             self.kv.pool, logits, new_lens = fn(
                 self.engine.params, self.kv.pool, dev["tables"], dev["lens"],
                 self.engine.put(counts), self.engine.put(toks))
@@ -412,7 +439,7 @@ class ContinuousBatcher:
         C = toks.shape[1]
         pos = lens[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
         out = self.model.apply(params, {"tokens": toks, "positions": pos},
-                               mode="decode", cache=cache)
+                               mode="decode", cache=cache, paged=True)
         last = jnp.clip(counts - 1, 0, C - 1)
         h = out["hidden"][jnp.arange(toks.shape[0]), last]
         logits = self.model.logits_of(params, h)
@@ -450,6 +477,7 @@ class ContinuousBatcher:
             key = ("cb_decode", B, nb, self.decode_impl)
             fn = self.engine._jit(key, self._decode_fn, donate=(1,))
             dev = self._device_state(active, nb)
+            self._count_window(dec, nb)
             self.kv.pool, nxt_dev, new_lens = fn(
                 self.engine.params, self.kv.pool, dev["tables"], dev["lens"],
                 dev["act"], self.engine.put(cur))
@@ -467,7 +495,7 @@ class ContinuousBatcher:
         cache = self.kv.gather(pool, tables, lens)
         with attention.use_decode_impl(self.decode_impl):
             out = self.model.apply(params, {"tokens": cur}, mode="decode",
-                                   cache=cache)
+                                   cache=cache, paged=True)
         logits = self.model.logits_of(params, out["hidden"][:, 0])
         pool = self.kv.scatter(pool, out["cache"], tables, lens, act, 1)
         return pool, jnp.argmax(logits, -1), lens + act
@@ -538,4 +566,6 @@ class ContinuousBatcher:
             "kv_peak_blocks": self.peak_blocks,
             "loop_s": self.loop_s,
             "readback_s": self.readback_s,
+            "window_blocks": self.window_blocks,
+            "window_blocks_outside": self.window_blocks_outside,
         }

@@ -56,7 +56,7 @@ class PagedKVCache:
             raise ValueError("num_blocks must be >= 2 (block 0 is scratch)")
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
-        template = model.init_cache(1, self.block_size)
+        template = model.init_cache(1, self.block_size, paged=True)
 
         def pool_leaf(x):
             a = _leaf_axis(x.shape, self.block_size)

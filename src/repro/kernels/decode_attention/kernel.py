@@ -6,6 +6,10 @@ online-softmax state in VMEM scratch; the q block is the [G, hd] group of
 query heads sharing one kv head (GQA), so the matmul shape is
 [G, hd] x [hd, block_k] -> MXU-friendly after sublane padding.
 
+With a ``window`` (a sliding-window layer over a full cache), positions
+before ``length - window`` are masked and KV blocks wholly before them are
+skipped too; ``window=None`` builds the kernel without either.
+
 KV blocks entirely beyond ``length`` are skipped (``pl.when``) — this is the
 structural analogue of not reading evicted pages on GPU serving stacks, and
 what makes the 500k-context decode cell latency proportional to the *valid*
@@ -29,7 +33,8 @@ NEG_INF = -1e30
 
 def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
                    acc_ref, m_ref, l_ref,
-                   *, sm_scale: float, block_k: int, seq_kv: int):
+                   *, sm_scale: float, block_k: int, seq_kv: int,
+                   window=None):
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
     k_start = ki * block_k
@@ -41,7 +46,11 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
 
-    @pl.when(k_start < length)
+    live = k_start < length
+    if window is not None:
+        live = jnp.logical_and(live, k_start + block_k > length - window)
+
+    @pl.when(live)
     def _body():
         q = q_ref[0, 0].astype(jnp.float32)                   # [G, hd]
         k = k_ref[0, 0].astype(jnp.float32)                   # [bk, hd]
@@ -52,6 +61,8 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         k_pos = k_start + jax.lax.broadcasted_iota(
             jnp.int32, logits.shape, 1)
         mask = jnp.logical_and(k_pos < length, k_pos < seq_kv)
+        if window is not None:
+            mask = jnp.logical_and(mask, k_pos >= length - window)
         logits = jnp.where(mask, logits, NEG_INF)
         m_prev, l_prev = m_ref[...], l_ref[...]
         m_cur = jnp.maximum(m_prev, jnp.max(logits, axis=-1, keepdims=True))
@@ -73,8 +84,9 @@ def _decode_kernel(len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 def decode_attention_kernel(q, k_cache, v_cache, lengths, *,
                             block_k: int = 512, interpret: bool,
-                            return_lse: bool = False):
-    """q: [B,H,hd]; k_cache,v_cache: [B,KV,Smax,hd]; lengths: [B].
+                            return_lse: bool = False, window=None):
+    """q: [B,H,hd]; k_cache,v_cache: [B,KV,Smax,hd]; lengths: [B];
+    window: if set, only positions >= lengths - window are read.
     Returns [B,H,hd] (and optionally the per-head logsumexp [B,H,1] for
     cross-shard combination)."""
     B, H, hd = q.shape
@@ -90,7 +102,8 @@ def decode_attention_kernel(q, k_cache, v_cache, lengths, *,
     qg = q.reshape(B, KV, G, hd)
 
     kernel = functools.partial(
-        _decode_kernel, sm_scale=scale, block_k=block_k, seq_kv=Smax)
+        _decode_kernel, sm_scale=scale, block_k=block_k, seq_kv=Smax,
+        window=window)
 
     out_shapes = [jax.ShapeDtypeStruct((B, KV, G, hd), q.dtype),
                   jax.ShapeDtypeStruct((B, KV, G, 1), jnp.float32)]

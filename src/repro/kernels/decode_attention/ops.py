@@ -21,10 +21,12 @@ def _on_tpu() -> bool:
     return jax.default_backend() == "tpu"
 
 
-@functools.partial(jax.jit, static_argnames=("impl", "block_k"))
+@functools.partial(jax.jit, static_argnames=("impl", "block_k", "window"))
 def flash_decode(q, k_cache, v_cache, lengths, *, impl: str = "auto",
-                 block_k: int = 512):
+                 block_k: int = 512, window=None):
     """q: [B,1,H,hd]; k_cache,v_cache: [B,Smax,KV,hd]; lengths: [B].
+    ``window``: if set, the token at position lengths-1 reads only the
+    positions at or after lengths-window (a sliding-window layer).
     Returns [B,1,H,hd]."""
     if impl == "auto":
         impl = "pallas" if _on_tpu() else "reference"
@@ -32,11 +34,11 @@ def flash_decode(q, k_cache, v_cache, lengths, *, impl: str = "auto",
     kc = jnp.swapaxes(k_cache, 1, 2)               # [B,KV,Smax,hd]
     vc = jnp.swapaxes(v_cache, 1, 2)
     if impl == "reference":
-        out = decode_attention_ref(q3, kc, vc, lengths)
+        out = decode_attention_ref(q3, kc, vc, lengths, window=window)
     else:
         out = decode_attention_kernel(
             q3, kc, vc, lengths, block_k=block_k,
-            interpret=(impl == "interpret"))
+            interpret=(impl == "interpret"), window=window)
     return out[:, None]
 
 

@@ -5,9 +5,10 @@ import jax
 import jax.numpy as jnp
 
 
-def decode_attention_ref(q, k_cache, v_cache, lengths):
+def decode_attention_ref(q, k_cache, v_cache, lengths, window=None):
     """q: [B,H,hd] (single new token, already at position lengths-1);
-    k_cache,v_cache: [B,KV,Smax,hd]; lengths: [B] valid tokens.
+    k_cache,v_cache: [B,KV,Smax,hd]; lengths: [B] valid tokens; window:
+    if set, only positions >= lengths - window are read.
     Returns [B,H,hd]."""
     B, H, hd = q.shape
     KV, Smax = k_cache.shape[1], k_cache.shape[2]
@@ -17,6 +18,8 @@ def decode_attention_ref(q, k_cache, v_cache, lengths):
                         preferred_element_type=jnp.float32) / jnp.sqrt(
                             jnp.float32(hd))
     valid = jnp.arange(Smax)[None] < lengths[:, None]          # [B,Smax]
+    if window is not None:
+        valid &= jnp.arange(Smax)[None] >= lengths[:, None] - window
     logits = jnp.where(valid[:, None, None], logits, -1e30)
     p = jax.nn.softmax(logits, axis=-1)
     out = jnp.einsum("bcgs,bcsd->bcgd", p.astype(v_cache.dtype), v_cache,

@@ -167,12 +167,14 @@ def local_attention(q, k, v, *, window: int):
     return out[:, :S]
 
 
-def decode_attention(q, k_cache, v_cache, lengths):
+def decode_attention(q, k_cache, v_cache, lengths, window: int = 0):
     """Decode-step attention against a full KV cache.
 
-    q: [B,T,H,hd] (T new tokens, already at positions lengths..lengths+T-1);
+    q: [B,T,H,hd] (T new tokens, already at positions lengths-T..lengths-1);
     k_cache,v_cache: [B,Smax,KV,hd] with the new tokens already written;
-    lengths: [B] — number of valid tokens *including* the new ones.
+    lengths: [B] — number of valid tokens *including* the new ones;
+    window: if set, the token at position t sees positions (t-window, t]
+    only (a sliding-window layer over a full cache).
 
     Sharding contract: the Smax axis may be sharded over the `model` mesh
     axis; the softmax reduction then induces collectives, which the Pallas
@@ -182,14 +184,14 @@ def decode_attention(q, k_cache, v_cache, lengths):
     if T == 1 and _DECODE_IMPL != "dense":
         from repro.kernels.decode_attention import ops as dec_ops
         return dec_ops.flash_decode(q, k_cache, v_cache, lengths,
-                                    impl=_DECODE_IMPL)
+                                    impl=_DECODE_IMPL, window=window or None)
     Smax = k_cache.shape[1]
     kv_pos = jnp.arange(Smax, dtype=jnp.int32)[None]           # [1,Smax]
     q_pos = (lengths[:, None] - T) + jnp.arange(T, dtype=jnp.int32)[None]
     valid = kv_pos < lengths[:, None]                          # [B,Smax]
     return _attn_block(q, k_cache, v_cache, q_pos,
                        jnp.broadcast_to(kv_pos, (B, Smax)),
-                       causal=True, kv_valid=valid)
+                       causal=True, window=window, kv_valid=valid)
 
 
 def ring_decode_attention(q, k_ring, v_ring, pos, window: int):

@@ -35,6 +35,9 @@ class Ctx:
     cache: Any = None               # this block's cache slice
     smax: int = 0                   # KV-cache capacity
     mesh_axes: Any = None           # (dp_axes, tp_axis) names or None
+    paged: bool = False             # decode caches are full [B, Smax] views
+                                    # of a paged pool for every attention
+                                    # layer, windowed ones too (no rings)
 
     def replace(self, **kw):
         return dataclasses.replace(self, **kw)
@@ -62,10 +65,12 @@ def attn_init(key, cfg: cfgs.ModelConfig, dtype):
     if cfg.qk_norm:
         p["q_norm"] = {"scale": jnp.ones((cfg.head_dim,), dtype)}
         p["k_norm"] = {"scale": jnp.ones((cfg.head_dim,), dtype)}
+    if cfg.attn_out_gate:
+        p["wgate"] = dense_init(ks[4], d, cfg.q_dim, dtype)
     return p
 
 
-def _qkv(params, x, cfg: cfgs.ModelConfig, positions):
+def _qkv(params, x, cfg: cfgs.ModelConfig, positions, rope: bool = True):
     B, S, _ = x.shape
     q = linear(x, params["wq"], params.get("bq")).reshape(
         B, S, cfg.num_heads, cfg.head_dim)
@@ -76,7 +81,7 @@ def _qkv(params, x, cfg: cfgs.ModelConfig, positions):
     if cfg.qk_norm:
         q = apply_norm(params["q_norm"], q, cfg.norm_eps)
         k = apply_norm(params["k_norm"], k, cfg.norm_eps)
-    if cfg.use_rope:
+    if rope:
         q = apply_rope(q, positions, cfg.rope_theta, cfg.mrope_sections)
         k = apply_rope(k, positions, cfg.rope_theta, cfg.mrope_sections)
     q = shardctx.constrain(q, "dp", None, "tp", None)
@@ -108,19 +113,21 @@ def _padded_heads(cfg: cfgs.ModelConfig) -> int:
 def attn_apply(params, x, ctx: Ctx, *, window: int):
     cfg = ctx.cfg
     B, S, _ = x.shape
-    q, k, v = _qkv(params, x, cfg, ctx.positions)
+    rope = cfg.use_rope and (bool(window) or not cfg.rope_local_only)
+    q, k, v = _qkv(params, x, cfg, ctx.positions, rope)
     Hp = _padded_heads(cfg)
     if Hp != cfg.num_heads:
         q = jnp.pad(q, ((0, 0), (0, 0), (0, Hp - cfg.num_heads), (0, 0)))
         q = shardctx.constrain(q, "dp", None, "tp", None)
     new_cache = ctx.cache
     if ctx.mode == "decode":
-        if window:
+        if window and not ctx.paged:
             pos = ctx.lengths - 1                       # absolute position
             ck, cv = attn.write_kv_ring(ctx.cache["k"], ctx.cache["v"],
                                         k, v, pos, window)
             out = attn.ring_decode_attention(q, ck, cv, pos, window)
-        elif S == 1 and attn.seq_sharded_decode_ready(ctx.cache["k"]):
+        elif (S == 1 and not window
+              and attn.seq_sharded_decode_ready(ctx.cache["k"])):
             # seq-sharded cache: shard-local write + logsumexp-combined
             # partial attention (kills the scatter-induced cache all-gather)
             out, ck, cv = attn.sharded_cache_decode(
@@ -128,7 +135,7 @@ def attn_apply(params, x, ctx: Ctx, *, window: int):
         else:
             start = ctx.lengths - S
             ck, cv = attn.write_kv(ctx.cache["k"], ctx.cache["v"], k, v, start)
-            out = attn.decode_attention(q, ck, cv, ctx.lengths)
+            out = attn.decode_attention(q, ck, cv, ctx.lengths, window=window)
         new_cache = {"k": ck, "v": cv}
     else:
         if window:
@@ -159,6 +166,12 @@ def attn_apply(params, x, ctx: Ctx, *, window: int):
                 cv = jax.lax.dynamic_update_slice_in_dim(cv, v, 0, axis=1)
                 new_cache = {"k": ck, "v": cv}
     out = out.reshape(B, S, Hp * cfg.head_dim)
+    if cfg.attn_out_gate:
+        gate = jax.nn.sigmoid(linear(x, params["wgate"]).astype(jnp.float32))
+        if Hp != cfg.num_heads:             # padded heads are zeroed by wo
+            gate = jnp.pad(gate, ((0, 0), (0, 0), (0, out.shape[-1]
+                                                   - gate.shape[-1])))
+        out = out * gate.astype(out.dtype)
     wo = params["wo"]
     if Hp != cfg.num_heads:                 # zero rows for padded heads
         wo = jnp.pad(wo, ((0, (Hp - cfg.num_heads) * cfg.head_dim), (0, 0)))
@@ -173,8 +186,15 @@ def attn_cache_init(cfg: cfgs.ModelConfig, batch: int, smax: int, *,
 
 
 # ===========================================================================
-# MoE MLP (token-choice top-k, capacity-based, EP over the `model` mesh axis)
+# MoE MLP: token-choice top-k.  Train mode keeps a per-expert capacity (EP
+# over the `model` mesh axis); inference is dropless, each (token, expert)
+# pair sorted by expert through one grouped matmul.
 # ===========================================================================
+
+# The dropless expert layer's grouped matmuls are jax.lax.ragged_dot: on a
+# TPU v5e, at a 256-token prefill step's shapes (2,048 pairs over 128
+# experts of 2048 x 1024), XLA's ragged dot took 5.4 ms for one layer's
+# experts against 18.0 ms for megablox's Pallas gmm.
 
 
 def moe_init(key, cfg: cfgs.ModelConfig, dtype):
@@ -184,8 +204,10 @@ def moe_init(key, cfg: cfgs.ModelConfig, dtype):
     d, f = cfg.d_model, m.expert_d_ff
 
     def stack(k, din, dout):
-        keys = jax.random.split(k, E)
-        return jnp.stack([dense_init(kk, din, dout, dtype) for kk in keys])
+        # one key per expert, as drawn one by one (vmap of the PRNG is the
+        # per-key draw), without E separate ops in the init program
+        return jax.vmap(lambda kk: dense_init(kk, din, dout, dtype))(
+            jax.random.split(k, E))
 
     p = {
         "router": {"w": dense_init(ks[0], d, E, jnp.float32)},
@@ -195,26 +217,54 @@ def moe_init(key, cfg: cfgs.ModelConfig, dtype):
             "wo": stack(ks[3], f, d),
         },
     }
+    if m.selection_bias:
+        # trained models learn it (load balancing); zeros would leave the
+        # selection-only path unexercised, so it is drawn from the seed
+        p["router"]["bias"] = 0.1 * jax.random.normal(
+            jax.random.fold_in(ks[0], 1), (E,), jnp.float32)
     if m.num_shared_experts:
         p["shared"] = mlp_init(ks[4], d, m.shared_d_ff, dtype, cfg.use_bias)
     return p
 
 
-def _route(router_w, x2d, m: cfgs.MoEConfig):
-    """x2d: [T,D] -> normalized top-k gates scattered to [T,E] (fp32)."""
-    logits = jnp.einsum("td,de->te", x2d.astype(jnp.float32), router_w)
+def _select(router, x2d, m: cfgs.MoEConfig):
+    """x2d: [T,D] -> (scores [T,E], top-k expert ids [T,k], their gates
+    [T,k]), fp32.  Scores are the softmax over experts or each expert's
+    sigmoid; the selection bias, if any, enters the choice of the top k
+    only; gates are the chosen scores over their sum, times
+    ``route_scale``.  The router's product runs at full float32
+    precision (the TPU's default would round its inputs to bfloat16), so
+    near-tied experts are chosen as a float32 model chooses them."""
+    logits = jnp.einsum("td,de->te", x2d.astype(jnp.float32), router["w"],
+                        precision=jax.lax.Precision.HIGHEST)
     E = m.padded_num_experts
     if E > m.num_experts:                     # mask padding experts
         pad_mask = jnp.arange(E) < m.num_experts
         logits = jnp.where(pad_mask[None], logits, -1e30)
-    probs = jax.nn.softmax(logits, axis=-1)
-    topv, topi = jax.lax.top_k(probs, m.num_experts_per_tok)
+    if m.score_func == "sigmoid":
+        scores = jax.nn.sigmoid(logits)
+    else:
+        scores = jax.nn.softmax(logits, axis=-1)
+    if m.selection_bias:
+        _, topi = jax.lax.top_k(scores + router["bias"][None],
+                                m.num_experts_per_tok)
+        topv = jnp.take_along_axis(scores, topi, axis=-1)
+    else:
+        topv, topi = jax.lax.top_k(scores, m.num_experts_per_tok)
     topv = topv / jnp.sum(topv, axis=-1, keepdims=True)
-    gates = jnp.zeros_like(probs)
+    if m.route_scale != 1.0:
+        topv = topv * m.route_scale
+    return scores, topi, topv
+
+
+def _route(router, x2d, m: cfgs.MoEConfig):
+    """x2d: [T,D] -> top-k gates scattered to [T,E] (fp32), aux loss."""
+    scores, topi, topv = _select(router, x2d, m)
+    gates = jnp.zeros_like(scores)
     gates = gates.at[jnp.arange(x2d.shape[0])[:, None], topi].set(topv)
     # Switch-style load balance aux loss (over true experts only)
     frac_tokens = jnp.mean((gates > 0).astype(jnp.float32), axis=0)
-    frac_probs = jnp.mean(probs, axis=0)
+    frac_probs = jnp.mean(scores, axis=0)
     aux = m.num_experts * jnp.sum(frac_tokens * frac_probs)
     return gates, aux * m.router_aux_loss
 
@@ -229,7 +279,6 @@ def _moe_local(x2d, gates_loc, wi, wg, wo, capacity: int):
     """
     T, D = x2d.shape
     E_loc = wi.shape[0]
-    sel = (gates_loc > 0).astype(jnp.float32)
     # top-capacity tokens per expert, priority = gate weight (stable ties)
     prio = jnp.swapaxes(gates_loc, 0, 1)                   # [E_loc, T]
     _, idx = jax.lax.top_k(prio, min(capacity, T))         # [E_loc, C]
@@ -246,14 +295,52 @@ def _moe_local(x2d, gates_loc, wi, wg, wo, capacity: int):
     return y
 
 
-def _capacity(m: cfgs.MoEConfig, t_loc: int, mode: str) -> int:
-    """Per-expert token capacity.  Decode is dropless (tiny T); train/prefill
-    use the capacity factor (overflow dropped by gate priority)."""
-    if mode == "decode":
-        return t_loc
+def _capacity(m: cfgs.MoEConfig, t_loc: int) -> int:
+    """Per-expert token capacity in train mode, from the capacity factor
+    (overflow dropped by gate priority).  Inference is dropless and has
+    none (``_moe_grouped``)."""
     import math
     return min(t_loc, max(1, math.ceil(
         m.num_experts_per_tok * t_loc * m.capacity_factor / m.num_experts)))
+
+
+def _grouped(lhs, rhs, group_sizes):
+    """Row block g of lhs (rows sorted by group) times rhs[g], in lhs's
+    dtype, accumulated in float32."""
+    return jax.lax.ragged_dot(lhs, rhs, group_sizes,
+                              preferred_element_type=jnp.float32
+                              ).astype(lhs.dtype)
+
+
+def _moe_grouped(x2d, topi, topv, experts, first=0):
+    """Dropless: every (token, expert) pair, sorted by expert, through one
+    grouped matmul per projection, then unsorted and summed with the gates.
+
+    x2d: [T,D]; topi/topv: [T,k] expert ids and gates; experts: stacked
+    wi/wg [E,D,F], wo [E,F,D], the experts ``first`` .. ``first + E - 1``
+    (a shard's slice); pairs routed elsewhere sort last, outside every
+    group, and add nothing.  Returns [T,D] in x2d's dtype.  The work
+    scales with k per token, not with the number of experts."""
+    T, D = x2d.shape
+    k = topi.shape[1]
+    E = experts["wi"].shape[0]
+    local = topi.reshape(-1) - first                       # [T*k]
+    mine = (local >= 0) & (local < E)
+    key = jnp.where(mine, local, E)
+    order = jnp.argsort(key, stable=True)
+    tok = order // k                                       # owning token
+    sizes = jnp.bincount(key, length=E + 1)[:E].astype(jnp.int32)
+    xs = x2d[tok]                                          # [T*k, D]
+    h = _grouped(xs, experts["wi"], sizes)
+    g = _grouped(xs, experts["wg"], sizes)
+    h = jax.nn.silu(g.astype(jnp.float32)).astype(h.dtype) * h
+    out = _grouped(h, experts["wo"], sizes)                # [T*k, D]
+    w = topv.reshape(-1)[order]
+    # rows past the groups are not computed: select, never multiply, them
+    part = jnp.where(mine[order][:, None],
+                     out.astype(jnp.float32) * w[:, None], 0.0)
+    y = jnp.zeros((T, D), jnp.float32).at[tok].add(part)
+    return y.astype(x2d.dtype)
 
 
 def moe_apply(params, x, ctx: Ctx):
@@ -261,7 +348,6 @@ def moe_apply(params, x, ctx: Ctx):
     m = cfg.moe
     B, S, D = x.shape
     x2d = x.reshape(B * S, D)
-    gates, aux = _route(params["router"]["w"], x2d, m)
     E = m.padded_num_experts
     mesh_axes = ctx.mesh_axes
     if mesh_axes is not None:
@@ -273,38 +359,56 @@ def moe_apply(params, x, ctx: Ctx):
             dp_size *= _mesh.shape[a]
         if (B * S) % dp_size != 0 or E % _mesh.shape[_tp] != 0:
             mesh_axes = None
-    if mesh_axes is not None:
+    if mesh_axes is None and ctx.mode != "train":
+        with jax.named_scope("moe.route"):
+            _, topi, topv = _select(params["router"], x2d, m)
+        with jax.named_scope("moe.experts"):
+            y2d = _moe_grouped(x2d, topi, topv, params["experts"])
+        aux = jnp.float32(0.0)
+    elif mesh_axes is not None:
         mesh, dp_axes, tp_axis = mesh_axes
         tp = mesh.shape[tp_axis]
         E_loc = E // tp
         P = jax.sharding.PartitionSpec
+        if ctx.mode == "train":
+            gates, aux = _route(params["router"], x2d, m)
+            route = (gates,)
+        else:                         # dropless: each shard its own pairs
+            _, topi, topv = _select(params["router"], x2d, m)
+            route, aux = (topi, topv), jnp.float32(0.0)
 
-        def local_fn(xl, gl, wi, wg, wo):
-            axis_idx = jax.lax.axis_index(tp_axis)
-            off = axis_idx * E_loc
-            g_slice = jax.lax.dynamic_slice_in_dim(gl, off, E_loc, axis=1)
-            cap = _capacity(m, xl.shape[0], ctx.mode)
-            y = _moe_local(xl, g_slice, wi, wg, wo, cap)
+        def local_fn(xl, *rest):
+            *rl, wi, wg, wo = rest
+            off = jax.lax.axis_index(tp_axis) * E_loc
+            if ctx.mode != "train":
+                y = _moe_grouped(xl, *rl, {"wi": wi, "wg": wg, "wo": wo},
+                                 first=off)
+            else:
+                g_slice = jax.lax.dynamic_slice_in_dim(rl[0], off, E_loc,
+                                                       axis=1)
+                cap = _capacity(m, xl.shape[0])
+                y = _moe_local(xl, g_slice, wi, wg, wo, cap)
             return jax.lax.psum(y, tp_axis)
 
         dp = dp_axes if isinstance(dp_axes, tuple) else (dp_axes,)
         y2d = jax.shard_map(
             local_fn,
             mesh=mesh,
-            in_specs=(P(dp, None), P(dp, None),
-                      P(tp_axis, None, None), P(tp_axis, None, None),
-                      P(tp_axis, None, None)),
+            in_specs=((P(dp, None),) * (1 + len(route))
+                      + (P(tp_axis, None, None),) * 3),
             out_specs=P(dp, None),
             check_vma=False,
-        )(x2d, gates, params["experts"]["wi"], params["experts"]["wg"],
+        )(x2d, *route, params["experts"]["wi"], params["experts"]["wg"],
           params["experts"]["wo"])
     else:
-        cap = _capacity(m, x2d.shape[0], ctx.mode)
+        gates, aux = _route(params["router"], x2d, m)
+        cap = _capacity(m, x2d.shape[0])
         y2d = _moe_local(x2d, gates, params["experts"]["wi"],
                          params["experts"]["wg"], params["experts"]["wo"], cap)
     y = y2d.reshape(B, S, D).astype(x.dtype)
     if m.num_shared_experts:
-        y = y + apply_mlp(params["shared"], x)
+        with jax.named_scope("moe.shared"):
+            y = y + apply_mlp(params["shared"], x)
     return y, aux
 
 
@@ -550,13 +654,21 @@ def rwkv_cache_init(cfg: cfgs.ModelConfig, batch: int, dtype):
 # ===========================================================================
 
 
-def block_init(blk: str, key, cfg: cfgs.ModelConfig, dtype):
+def block_init(blk: str, key, cfg: cfgs.ModelConfig, dtype,
+               dense: bool = False):
+    """``dense``: a dense MLP of width d_ff even where ``cfg.moe`` is set
+    (the leading layers of an MoE model)."""
     k1, k2, k3 = jax.random.split(key, 3)
     if blk in (cfgs.ATTN, cfgs.LOCAL_ATTN):
         p = {"ln1": norm_init(cfg.d_model, dtype, cfg.use_layernorm),
              "ln2": norm_init(cfg.d_model, dtype, cfg.use_layernorm),
              "attn": attn_init(k1, cfg, dtype)}
-        if cfg.moe is not None:
+        if cfg.sandwich_norm:
+            p["post_attn_norm"] = norm_init(cfg.d_model, dtype,
+                                            cfg.use_layernorm)
+            p["post_mlp_norm"] = norm_init(cfg.d_model, dtype,
+                                           cfg.use_layernorm)
+        if cfg.moe is not None and not dense:
             p["moe"] = moe_init(k2, cfg, dtype)
         else:
             p["mlp"] = mlp_init(k2, cfg.d_model, cfg.d_ff, dtype, cfg.use_bias)
@@ -574,12 +686,16 @@ def block_init(blk: str, key, cfg: cfgs.ModelConfig, dtype):
 
 
 def block_cache_init(blk: str, cfg: cfgs.ModelConfig, batch: int, smax: int,
-                     dtype):
+                     dtype, paged: bool = False):
+    """``paged``: every attention layer's cache is a full [B, smax] one,
+    windowed layers too (the paged pool's template); else a windowed
+    layer keeps a ring of its last ``attention_window`` positions."""
     if blk == cfgs.ATTN:
         return attn_cache_init(cfg, batch, smax, window=0, dtype=dtype)
     if blk == cfgs.LOCAL_ATTN:
         return attn_cache_init(cfg, batch, smax,
-                               window=cfg.attention_window, dtype=dtype)
+                               window=0 if paged else cfg.attention_window,
+                               dtype=dtype)
     if blk == cfgs.RGLRU:
         return rglru_cache_init(cfg, batch, dtype)
     if blk == cfgs.RWKV:
@@ -595,18 +711,24 @@ def block_apply(blk: str, params, x, ctx: Ctx):
         h1 = apply_norm(params["ln1"], x, cfg.norm_eps)
         a_out, new_cache = attn_apply(params["attn"], h1, ctx, window=window)
         if cfg.parallel_block:
-            if cfg.moe is not None:
+            if "moe" in params:
                 m_out, aux = moe_apply(params["moe"], h1, ctx)
             else:
                 m_out = apply_mlp(params["mlp"], h1)
             y = x + a_out + m_out
         else:
+            if cfg.sandwich_norm:
+                a_out = apply_norm(params["post_attn_norm"], a_out,
+                                   cfg.norm_eps)
             x = x + a_out
             h2 = apply_norm(params["ln2"], x, cfg.norm_eps)
-            if cfg.moe is not None:
+            if "moe" in params:
                 m_out, aux = moe_apply(params["moe"], h2, ctx)
             else:
                 m_out = apply_mlp(params["mlp"], h2)
+            if cfg.sandwich_norm:
+                m_out = apply_norm(params["post_mlp_norm"], m_out,
+                                   cfg.norm_eps)
             y = x + m_out
         return y, new_cache, aux
     if blk == cfgs.RGLRU:

@@ -1,8 +1,9 @@
 """Generic decoder-only language model assembled from a block pattern.
 
-The model is ``num_periods`` repetitions of ``cfg.period`` (scanned, with
-stacked params — keeps HLO small for 64-layer models) followed by ``cfg.tail``
-(unrolled).  Supports three modes:
+The model is ``cfg.lead`` (unrolled; dense-MLP layers of an MoE model), then
+``num_periods`` repetitions of ``cfg.period`` (scanned, with stacked params —
+keeps HLO small for 64-layer models), then ``cfg.tail`` (unrolled).  Supports
+three modes:
 
   * train:   full sequence, no cache, returns hidden states (+ aux loss)
   * prefill: full (right-padded) sequence, writes decode caches, returns the
@@ -58,13 +59,22 @@ def init_params(cfg: cfgs.ModelConfig, key, dtype=None) -> Dict[str, Any]:
         params["tail"] = {
             f"t{i}": blocks.block_init(blk, tail_keys[i], cfg, dtype)
             for i, blk in enumerate(cfg.tail)}
+    if cfg.lead:
+        lead_keys = jax.random.split(keys[5], len(cfg.lead))
+        params["lead"] = {
+            f"l{i}": blocks.block_init(blk, lead_keys[i], cfg, dtype,
+                                       dense=True)
+            for i, blk in enumerate(cfg.lead)}
     return params
 
 
 def init_cache(cfg: cfgs.ModelConfig, batch: int, smax: int,
-               dtype=None) -> Dict[str, Any]:
+               dtype=None, paged: bool = False) -> Dict[str, Any]:
+    """``paged``: the paged pool's template, a full [batch, smax] cache
+    for every attention layer (windowed ones too, not a ring)."""
     dtype = dtype or dtype_of(cfg.dtype)
-    one = lambda blk: blocks.block_cache_init(blk, cfg, batch, smax, dtype)
+    one = lambda blk: blocks.block_cache_init(blk, cfg, batch, smax, dtype,
+                                              paged=paged)
     periods = {}
     for i, blk in enumerate(cfg.period):
         c = one(blk)
@@ -74,6 +84,8 @@ def init_cache(cfg: cfgs.ModelConfig, batch: int, smax: int,
                              "len": jnp.zeros((batch,), jnp.int32)}
     if cfg.tail:
         cache["tail"] = {f"t{i}": one(blk) for i, blk in enumerate(cfg.tail)}
+    if cfg.lead:
+        cache["lead"] = {f"l{i}": one(blk) for i, blk in enumerate(cfg.lead)}
     return cache
 
 
@@ -113,10 +125,14 @@ def _default_positions(cfg, batch, x, mode, lengths):
 
 
 def apply(cfg: cfgs.ModelConfig, params, batch, *, mode: str,
-          cache=None, mesh_axes=None, remat: bool = True):
+          cache=None, mesh_axes=None, remat: bool = True,
+          paged: bool = False):
     """Run the backbone.  Returns dict with:
        hidden [B,S,D] (train) or last_hidden [B,D] (prefill) or
        hidden [B,1,D] (decode); new cache (prefill/decode); aux loss.
+       ``paged``: the decode cache is a dense view of a paged pool
+       (``init_cache(..., paged=True)``), windowed layers masking their
+       window over it.
     """
     assert mode in ("train", "prefill", "decode")
     if mesh_axes is None and shardctx.enabled():
@@ -138,8 +154,15 @@ def apply(cfg: cfgs.ModelConfig, params, batch, *, mode: str,
             lengths = cache["len"] + S                 # S new tokens
     positions = _default_positions(cfg, batch, x, mode, lengths)
     ctx = blocks.Ctx(cfg=cfg, mode=mode, positions=positions, lengths=lengths,
-                     valid=valid, smax=cache_capacity(cache),
-                     mesh_axes=mesh_axes)
+                     valid=valid, smax=cache_capacity(cache, cfg),
+                     mesh_axes=mesh_axes, paged=paged)
+
+    lead_aux, lead_caches = [], {}
+    for i, blk in enumerate(cfg.lead):
+        c = None if mode == "train" else cache["lead"][f"l{i}"]
+        x, lead_caches[f"l{i}"], a = blocks.block_apply(
+            blk, params["lead"][f"l{i}"], x, ctx.replace(cache=c))
+        lead_aux.append(a)
 
     def period_body(carry, xs):
         h = carry
@@ -170,7 +193,11 @@ def apply(cfg: cfgs.ModelConfig, params, batch, *, mode: str,
             body, x, (params["periods"], cache["periods"]))
         new_cache = dict(cache)
         new_cache["periods"] = new_period_caches
+        if cfg.lead:
+            new_cache["lead"] = lead_caches
     aux = jnp.sum(auxs)
+    for a in lead_aux:
+        aux = aux + a
 
     if cfg.tail:
         if new_cache is not None:
@@ -200,13 +227,23 @@ def apply(cfg: cfgs.ModelConfig, params, batch, *, mode: str,
     return out
 
 
-def cache_capacity(cache) -> int:
+def cache_capacity(cache, cfg: cfgs.ModelConfig) -> int:
+    """Positions a full (global-attention) layer's cache holds, read off a
+    global layer so a windowed layer's ring is never taken for it; a model
+    without one gives its first cache's length."""
     if cache is None:
         return 0
+    for group, kinds, tag, axis in (("lead", cfg.lead, "l", 1),
+                                    ("periods", cfg.period, "b", 2),
+                                    ("tail", cfg.tail, "t", 1)):
+        for i, blk in enumerate(kinds):
+            if blk == cfgs.ATTN:
+                return cache[group][f"{tag}{i}"]["k"].shape[axis]
     for k in cache.get("periods", {}).values():
         if "k" in k:
             return k["k"].shape[2]  # [P, B, Smax, KV, hd]
-    for k in cache.get("tail", {}).values():
+    for k in list(cache.get("tail", {}).values()) + list(
+            cache.get("lead", {}).values()):
         if "k" in k:
             return k["k"].shape[1]
     return 0

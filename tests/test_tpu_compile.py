@@ -81,3 +81,35 @@ def test_similarity_topk_compiles_for_v5e(one_chip):
         lambda q, c: similarity_topk_kernel(q, c, 10, interpret=False),
         one_chip, ((64, 768), jnp.float32), ((100_000, 768), jnp.float32))
     assert "tpu_custom_call" in text
+
+
+# Trinity-Mini's widths: 32 query heads over 4 KV heads of 128, a window of
+# 2048 over an 8k cache; 128 experts of 2048 x 1024, 8 a token over a
+# 256-token prefill step (8 slots x 32).
+T_KV, T_S, T_W = 4, 8192, 2048
+
+
+def test_windowed_flash_decode_compiles_for_v5e(one_chip):
+    text = _compile_text(
+        lambda q, k, v, n: decode_attention_kernel(q, k, v, n,
+                                                   interpret=False,
+                                                   window=T_W),
+        one_chip, ((B, H, HD), jnp.bfloat16),
+        ((B, T_KV, T_S, HD), jnp.bfloat16),
+        ((B, T_KV, T_S, HD), jnp.bfloat16), ((B,), jnp.int32))
+    assert re.search(r'%flash_decode[.\d]* = .*custom_call_target='
+                     r'"tpu_custom_call"', text)
+
+
+@pytest.mark.parametrize("rows,d_in,d_out", [(2048, 2048, 1024),
+                                             (2048, 1024, 2048),
+                                             (64, 2048, 1024)])
+def test_grouped_expert_matmul_compiles_for_v5e(one_chip, rows, d_in, d_out):
+    text = _compile_text(
+        lambda x, w, g: jax.lax.ragged_dot(x, w, g,
+                                           preferred_element_type=jnp.float32),
+        one_chip, ((rows, d_in), jnp.bfloat16),
+        ((128, d_in, d_out), jnp.bfloat16), ((128,), jnp.int32))
+    # a Mosaic call, named as the expert readers find it in a device trace
+    assert re.search(r'%ragged-dot[-\w.]* = .*custom_call_target='
+                     r'"tpu_custom_call"', text)
