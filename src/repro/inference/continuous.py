@@ -11,8 +11,13 @@ This module is the backend the paper's serving layer actually wants:
     return to the pool, and queued requests are admitted into the freed
     slots — admission happens at *every* step, not at batch boundaries;
   * **paged KV** — each sequence owns a block table over a shared pool
-    (``paged_kv.PagedKVCache``); a step gathers the dense view, runs the
-    model, and scatters only the newly valid keys/values back;
+    (``paged_kv.PagedKVCache``).  A prefill step hands the model the pool
+    itself: each attention layer writes its chunk's keys and values into
+    the rows' own blocks and attends through the tables
+    (``attention.paged_attention``: a Pallas kernel on TPU, a jnp
+    reference elsewhere, chosen once per batcher), with no dense copy of
+    the cache.  A decode step gathers the dense view, runs the model and
+    scatters the new keys/values back;
   * **chunked prefill** — prompts enter the cache ``prefill_chunk`` tokens
     at a time, batched across every prefilling slot and interleaved with
     decode steps, so a long prompt never stalls in-flight decodes for its
@@ -60,12 +65,17 @@ readbacks the loop needs anyway.
 
 Sliding-window layers page like global ones: each keeps a full per-layer
 paged cache, and its attention masks the positions before the window
-(``attention.decode_attention``, ``flash_decode``'s ``window``).  So a
-step gathers, for a windowed layer, blocks it then masks away:
-``window_blocks`` counts the KV blocks each step gathered for its
-windowed layers, over the rows it advanced and those layers, and
-``window_blocks_outside`` those of them wholly before the row's window
-(before the first position the row's earliest new token sees).
+(``attention.decode_attention``, ``flash_decode``'s ``window``, the paged
+kernel's ``sliding_window``).  So a step's tables cover, for a windowed
+layer, blocks it then masks away: ``window_blocks`` counts the KV blocks
+of each step's table width for its windowed layers, over the rows it
+advanced and those layers, and ``window_blocks_outside`` those of them
+wholly before the row's window (before the first position the row's
+earliest new token sees).  ``prefill_pool_steps`` counts the prefill
+steps whose attention ran the paged-attention kernel over the pool, and
+``kv_blocks_read`` the blocks those calls covered: over every slot,
+⌈kv_len / block_size⌉ per attention layer (``_kv_lens``).  Off TPU the
+reference runs and both stay 0.
 """
 from __future__ import annotations
 
@@ -75,6 +85,7 @@ import time
 from collections import deque
 from typing import Any, Deque, Dict, List, Optional, Sequence
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -83,7 +94,8 @@ from repro.inference import tokenizer as tok
 from repro.inference.backend import (COMPLETE, SCORE, EngineFailure, Request,
                                      Result, credits_for)
 from repro.inference.paged_kv import PagedKVCache
-from repro.models import attention
+from repro.kernels.paged_attention import ops as paged_ops
+from repro.models import attention, blocks
 from repro.obs.trace import active_tracer
 
 
@@ -98,11 +110,27 @@ def supports(cfg) -> bool:
     return all(b in (cfgs.ATTN, cfgs.LOCAL_ATTN) for b in cfg.block_pattern)
 
 
+def _kv_lens(lens, counts, C: int):
+    """Positions each row of a C-token prefill chunk reads: through its
+    whole chunk where it advances (its queries sit at ``lens .. lens +
+    C - 1``, the pads past ``counts`` read only what they may), else one
+    chunk's worth, which nothing trusts.  Works on numpy and jnp arrays."""
+    return (counts > 0) * lens + C
+
+
 def _pow2(n: int) -> int:
     b = 1
     while b < n:
         b *= 2
     return b
+
+
+def _kernel_width(horizon: int, bs: int, max_blocks: int) -> int:
+    """Table width the paged-attention kernel reads in a prefill step: the
+    bucket (``_gather_width``) of a row at ``horizon`` tokens, the pool's
+    sizing of a sequence.  Every narrower bucket's step pads its tables to
+    it, so they run one kernel shape and trace it once."""
+    return min(_pow2(-(-horizon // bs)), max_blocks)
 
 
 class _Caller:
@@ -151,15 +179,20 @@ class ContinuousBatcher:
         self.block_size = int(block_size)
         self.prefill_chunk = int(prefill_chunk)
         self.decode_impl = decode_impl
+        # a full-length prompt plus a generous generation budget
+        horizon = engine.max_seq + 4 * self.prefill_chunk
         if num_blocks is None:
-            # every slot can hold a full-length prompt plus a generous
-            # generation budget; +1 for the sacrificial scratch block
-            per_seq = -(-(engine.max_seq + 4 * self.prefill_chunk)
-                        // self.block_size)
+            # every slot can hold one; +1 for the sacrificial scratch block
+            per_seq = -(-horizon // self.block_size)
             num_blocks = self.slots * per_seq + 1
         self.kv = PagedKVCache(self.model, block_size=self.block_size,
                                num_blocks=num_blocks, device=engine.device)
         width = self.kv.max_seq_blocks
+        # prefill attention over the pool: the kernel on TPU, else the
+        # reference (module docstring), and the tables' width it reads
+        self.paged_impl = paged_ops.impl_for(
+            self.model.cfg.num_heads, jax.tree.leaves(self.kv.pool)[0])
+        self._kernel_width = _kernel_width(horizon, self.block_size, width)
         self.tables_np = np.zeros((self.slots, width), np.int32)
         self.lens_np = np.zeros((self.slots,), np.int32)
         # device mirror of (block tables, lengths, decode-active mask),
@@ -186,8 +219,11 @@ class ContinuousBatcher:
         self.readback_s = 0.0      # of which blocked reading step outputs
         self._windowed = sum(b == cfgs.LOCAL_ATTN
                              for b in self.model.cfg.block_pattern)
+        self._layers = len(self.model.cfg.block_pattern)   # all attention
         self.window_blocks = 0     # gathered for windowed layers (docstring)
         self.window_blocks_outside = 0   # of which wholly before the window
+        self.prefill_pool_steps = 0   # ran the paged kernel (docstring)
+        self.kv_blocks_read = 0    # blocks their attention layers covered
 
     # ------------------------------------------------------------------
     # serve loop
@@ -416,6 +452,11 @@ class ContinuousBatcher:
             fn = self.engine._jit(key, self._prefill_fn, donate=(1,))
             dev = self._device_state(active, nb)
             self._count_window(pre, nb)
+            if self.paged_impl == "pallas":
+                kv_lens = _kv_lens(self.lens_np, counts, C)
+                self.prefill_pool_steps += 1
+                self.kv_blocks_read += self._layers * int(
+                    (-(-kv_lens // self.block_size)).sum())
             self.kv.pool, logits, new_lens = fn(
                 self.engine.params, self.kv.pool, dev["tables"], dev["lens"],
                 self.engine.put(counts), self.engine.put(toks))
@@ -435,15 +476,24 @@ class ContinuousBatcher:
                 self._finish_prefill(s, lf[s.slot], active)
 
     def _prefill_fn(self, params, pool, tables, lens, counts, toks):
-        cache = self.kv.gather(pool, tables, lens)
         C = toks.shape[1]
         pos = lens[:, None] + jnp.arange(C, dtype=jnp.int32)[None]
+        # each attention layer writes its chunk into the rows' blocks and
+        # reads them back through the tables (module docstring)
+        blk, off = self.kv.write_index(tables, lens, counts, C)
+        if self.paged_impl == "pallas":
+            tables = jnp.pad(tables, ((0, 0), (0, max(
+                self._kernel_width - tables.shape[1], 0))))
+        pages = blocks.Pages(tables=tables, blk=blk, off=off,
+                             kv_lens=_kv_lens(lens, counts, C),
+                             impl=self.paged_impl)
         out = self.model.apply(params, {"tokens": toks, "positions": pos},
-                               mode="decode", cache=cache, paged=True)
+                               mode="decode", cache=dict(pool, len=lens),
+                               pages=pages)
+        pool = {k: out["cache"][k] for k in pool}
         last = jnp.clip(counts - 1, 0, C - 1)
         h = out["hidden"][jnp.arange(toks.shape[0]), last]
         logits = self.model.logits_of(params, h)
-        pool = self.kv.scatter(pool, out["cache"], tables, lens, counts, C)
         return pool, logits, lens + counts
 
     def _finish_prefill(self, s: _Seq, logits_row: np.ndarray, active
@@ -568,4 +618,6 @@ class ContinuousBatcher:
             "readback_s": self.readback_s,
             "window_blocks": self.window_blocks,
             "window_blocks_outside": self.window_blocks_outside,
+            "prefill_pool_steps": self.prefill_pool_steps,
+            "kv_blocks_read": self.kv_blocks_read,
         }

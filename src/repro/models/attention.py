@@ -194,6 +194,33 @@ def decode_attention(q, k_cache, v_cache, lengths, window: int = 0):
                        causal=True, window=window, kv_valid=valid)
 
 
+def paged_attention(q, pages, tables, kv_lens, *, impl: str,
+                    window: int = 0):
+    """Chunk attention read straight from a paged KV pool.
+
+    q: [B,T,H,hd], row b's queries at positions kv_lens[b]-T ..
+    kv_lens[b]-1; pages: [N,bs,2KV,hd], keys at even heads and values at
+    odd ones, the chunk's own already written; tables: int32 [B,nb], each
+    row's pages in order; kv_lens: [B] positions each row reads.
+    ``impl`` (``paged_attention.ops.impl_for``): "pallas" runs the ragged
+    paged-attention kernel; "reference" gathers each row's pages through
+    its table and runs :func:`decode_attention`'s masked attention over
+    them.
+    """
+    from repro.kernels.paged_attention import ops as paged_ops
+    if impl == "pallas":
+        return paged_ops.paged_attention(q, pages, tables, kv_lens,
+                                         window=window or None)
+    k, v = paged_ops.gather_pages(pages, tables)
+    B, T = q.shape[:2]
+    Smax = k.shape[1]
+    kv_pos = jnp.arange(Smax, dtype=jnp.int32)[None]           # [1,Smax]
+    q_pos = (kv_lens[:, None] - T) + jnp.arange(T, dtype=jnp.int32)[None]
+    valid = kv_pos < kv_lens[:, None]                          # [B,Smax]
+    return _attn_block(q, k, v, q_pos, jnp.broadcast_to(kv_pos, (B, Smax)),
+                       causal=True, window=window, kv_valid=valid)
+
+
 def ring_decode_attention(q, k_ring, v_ring, pos, window: int):
     """Sliding-window decode against a ring buffer of the last W tokens.
 

@@ -38,9 +38,32 @@ class Ctx:
     paged: bool = False             # decode caches are full [B, Smax] views
                                     # of a paged pool for every attention
                                     # layer, windowed ones too (no rings)
+    pages: Optional["Pages"] = None   # decode caches are the paged pool
+                                      # itself, written and read in place
 
     def replace(self, **kw):
         return dataclasses.replace(self, **kw)
+
+
+@dataclasses.dataclass(frozen=True)
+class Pages:
+    """A decode-mode chunk over a paged KV pool, in place (``lm.apply(...,
+    pages=)``): each attention layer's cache is its pool leaf ``{"kv":
+    [N, bs, 2 KV, hd]}`` (``inference/paged_kv.py``), into which the layer
+    writes its chunk's keys and values, then attends through the tables.
+
+    A scanned period's leaf is the periods' pool flattened to
+    ``[P * NB, ...]``, and ``base`` the first block of the layer's own
+    ``NB``; the tables, the write blocks and ``base`` index the leaf."""
+    tables: Any      # int32 [B, nb]: each row's pool blocks, in order
+    blk: Any         # int32 [B, C]: each new token's block; -1 = not written
+    off: Any         # int32 [B, C]: its offset in the block
+    kv_lens: Any     # int32 [B]: positions each row's queries read
+    impl: str = "reference"          # attention.paged_attention's
+    base: Any = 0                    # int32 scalar, added to block ids
+
+    def at(self, base) -> "Pages":
+        return dataclasses.replace(self, base=base)
 
 
 # ===========================================================================
@@ -120,7 +143,17 @@ def attn_apply(params, x, ctx: Ctx, *, window: int):
         q = jnp.pad(q, ((0, 0), (0, 0), (0, Hp - cfg.num_heads), (0, 0)))
         q = shardctx.constrain(q, "dp", None, "tp", None)
     new_cache = ctx.cache
-    if ctx.mode == "decode":
+    if ctx.mode == "decode" and ctx.pages is not None:
+        pg = ctx.pages
+        pool = ctx.cache["kv"]
+        n = pool.shape[0]
+        dst = jnp.where(pg.blk >= 0, pg.blk + pg.base, n)      # n: dropped
+        pool = pool.at[dst, pg.off].set(
+            interleave_kv(k, v).astype(pool.dtype), mode="drop")
+        out = attn.paged_attention(q, pool, pg.tables + pg.base, pg.kv_lens,
+                                   impl=pg.impl, window=window)
+        new_cache = {"kv": pool}
+    elif ctx.mode == "decode":
         if window and not ctx.paged:
             pos = ctx.lengths - 1                       # absolute position
             ck, cv = attn.write_kv_ring(ctx.cache["k"], ctx.cache["v"],
@@ -176,6 +209,13 @@ def attn_apply(params, x, ctx: Ctx, *, window: int):
     if Hp != cfg.num_heads:                 # zero rows for padded heads
         wo = jnp.pad(wo, ((0, (Hp - cfg.num_heads) * cfg.head_dim), (0, 0)))
     return linear(out, wo, params.get("bo")), new_cache
+
+
+def interleave_kv(k, v):
+    """k, v: [..., KV, hd] -> [..., 2 KV, hd], key head h at 2h and value
+    head h at 2h + 1: the paged pool's head layout."""
+    kv = jnp.stack([k, v], axis=-2)                        # [..., KV, 2, hd]
+    return kv.reshape(k.shape[:-2] + (2 * k.shape[-2], k.shape[-1]))
 
 
 def attn_cache_init(cfg: cfgs.ModelConfig, batch: int, smax: int, *,

@@ -126,13 +126,17 @@ def _default_positions(cfg, batch, x, mode, lengths):
 
 def apply(cfg: cfgs.ModelConfig, params, batch, *, mode: str,
           cache=None, mesh_axes=None, remat: bool = True,
-          paged: bool = False):
+          paged: bool = False, pages: Optional[blocks.Pages] = None):
     """Run the backbone.  Returns dict with:
        hidden [B,S,D] (train) or last_hidden [B,D] (prefill) or
        hidden [B,1,D] (decode); new cache (prefill/decode); aux loss.
        ``paged``: the decode cache is a dense view of a paged pool
        (``init_cache(..., paged=True)``), windowed layers masking their
        window over it.
+       ``pages`` (decode): the cache is the paged pool itself (its groups,
+       plus ``"len"``), which each attention layer writes and reads in
+       place (``blocks.Pages``); the scanned periods' pool rides the scan's
+       carry, flattened to [P * NB, ...], and comes back whole.
     """
     assert mode in ("train", "prefill", "decode")
     if mesh_axes is None and shardctx.enabled():
@@ -153,9 +157,10 @@ def apply(cfg: cfgs.ModelConfig, params, batch, *, mode: str,
         else:
             lengths = cache["len"] + S                 # S new tokens
     positions = _default_positions(cfg, batch, x, mode, lengths)
+    smax = 0 if pages is not None else cache_capacity(cache, cfg)
     ctx = blocks.Ctx(cfg=cfg, mode=mode, positions=positions, lengths=lengths,
-                     valid=valid, smax=cache_capacity(cache, cfg),
-                     mesh_axes=mesh_axes, paged=paged)
+                     valid=valid, smax=smax, mesh_axes=mesh_axes, paged=paged,
+                     pages=pages)
 
     lead_aux, lead_caches = [], {}
     for i, blk in enumerate(cfg.lead):
@@ -164,21 +169,32 @@ def apply(cfg: cfgs.ModelConfig, params, batch, *, mode: str,
             blk, params["lead"][f"l{i}"], x, ctx.replace(cache=c))
         lead_aux.append(a)
 
-    def period_body(carry, xs):
-        h = carry
-        p_params, p_cache = xs
+    def run_period(h, p_params, p_cache, pctx):
         new_caches = {}
         aux = jnp.float32(0.0)
         for i, blk in enumerate(cfg.period):
             c = None if p_cache is None else p_cache[f"b{i}"]
             h, nc, a = blocks.block_apply(blk, p_params[f"b{i}"], h,
-                                          ctx.replace(cache=c))
+                                          pctx.replace(cache=c))
             new_caches[f"b{i}"] = nc
             aux = aux + a
         # pin the layer-to-layer carry: batch on dp; with sequence
         # parallelism on, activations are also sharded over `model` on S
         h = shardctx.constrain(h, "dp", "sp", None)
+        return h, new_caches, aux
+
+    def period_body(carry, xs):
+        p_params, p_cache = xs
+        h, new_caches, aux = run_period(carry, p_params, p_cache, ctx)
         return h, (new_caches, aux)
+
+    def pool_body(carry, xs):
+        # period i's layers address their blocks in the flattened pool
+        h, pool = carry
+        p_params, i = xs
+        h, pool, aux = run_period(h, p_params, pool,
+                                  ctx.replace(pages=pages.at(i * nb_pool)))
+        return (h, pool), aux
 
     if mode == "train":
         body = lambda h, p: period_body(h, (p, None))
@@ -187,6 +203,18 @@ def apply(cfg: cfgs.ModelConfig, params, batch, *, mode: str,
                 body, policy=jax.checkpoint_policies.nothing_saveable)
         x, (_, auxs) = jax.lax.scan(body, x, params["periods"])
         new_cache = None
+    elif pages is not None:
+        periods = cache["periods"]
+        nb_pool = jax.tree.leaves(periods)[0].shape[1]
+        flat = jax.tree.map(lambda a: a.reshape((-1,) + a.shape[2:]), periods)
+        (x, flat), auxs = jax.lax.scan(
+            pool_body, (x, flat),
+            (params["periods"], jnp.arange(cfg.num_periods, dtype=jnp.int32)))
+        new_cache = dict(cache)
+        new_cache["periods"] = jax.tree.map(
+            lambda a, f: f.reshape(a.shape), periods, flat)
+        if cfg.lead:
+            new_cache["lead"] = lead_caches
     else:
         body = period_body
         x, (new_period_caches, auxs) = jax.lax.scan(

@@ -1,8 +1,8 @@
 """The program pieces an AFMoE model (Trinity) brings: the sigmoid router
 with a selection-only bias, the dropless grouped expert layer, sliding
 windows over a full (paged) cache, the flash-decode kernel's window, and
-the window counters of the continuous batcher; plus the pin that none of
-it changed what the dense Qwen3 steps compile to."""
+the window counters of the continuous batcher; plus a pin of what the
+dense Qwen3 steps compile to."""
 import dataclasses
 import hashlib
 import json
@@ -308,10 +308,15 @@ def test_window_counters_stay_zero_without_sliding_layers():
 # ---------------------------------------------------------------------------
 
 # sha256 of the StableHLO (no debug info) of qwen3-8b's continuous-batching
-# steps at a 4-block gather, as lowered before the AFMoE pieces were added.
+# steps at a 4-block gather, as lowered once the pool holds each layer's
+# keys and values in one interleaved leaf: the prefill step writes and reads
+# that pool in place (tests/test_paged_prefill.py holds it to the
+# gather-run-scatter step it replaced), and the decode step gathers it
+# with one ``jnp.take`` over the layers' blocks flattened, in "clip" mode
+# (tests/test_tpu_compile.py holds that gather to no loop over blocks).
 QWEN3_STEPS_SHA256 = {
-    "prefill": "b88575704a62a03a07d66e566151d5c095c3bacc41e2ca175a8fb20e11d4edc5",
-    "decode": "1d5f992cd2a26b3324fef6e40e3ff2857f8eed380446af87e4b7961697f37fde",
+    "prefill": "4c527458b0388b6475915118d68d5906002ca971277d488c8878a553a5c09a0a",
+    "decode": "a5826cc8f8b82c6f4ec477b47b510fbf2595fa0cc7c80625969db7c1a03dd299",
 }
 
 
@@ -330,7 +335,8 @@ def test_qwen3_steps_lower_to_the_programs_they_were():
     kv = PagedKVCache(model, block_size=32, num_blocks=2)
     pool = jax.tree.map(lambda x: jax.ShapeDtypeStruct(
         x.shape[:1] + (545,) + x.shape[2:], x.dtype), kv.pool)
-    batcher = types.SimpleNamespace(model=model, kv=kv, decode_impl="auto")
+    batcher = types.SimpleNamespace(model=model, kv=kv, decode_impl="auto",
+                                    paged_impl="reference")
 
     def i32(*shape):
         return jax.ShapeDtypeStruct(shape, jnp.int32)

@@ -17,6 +17,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.kernels.decode_attention.kernel import decode_attention_kernel
 from repro.kernels.decode_attention.ops import flash_decode_paged
+from repro.kernels.paged_attention.ops import paged_attention
 from repro.kernels.similarity_topk.kernel import similarity_topk_kernel
 
 B, H, KV, HD = 8, 32, 8, 128          # proxy-8b: 32 heads over 8 KV heads
@@ -113,3 +114,86 @@ def test_grouped_expert_matmul_compiles_for_v5e(one_chip, rows, d_in, d_out):
     # a Mosaic call, named as the expert readers find it in a device trace
     assert re.search(r'%ragged-dot[-\w.]* = .*custom_call_target='
                      r'"tpu_custom_call"', text)
+
+
+# Chunked prefill read straight from the paged pool: 8 slots x 32 tokens,
+# pages of 32 with keys and values interleaved over the heads, the tables
+# at the width the batcher pads every step's to (``_kernel_width`` of the
+# cell's max_seq, test_paged_prefill.py): qwen3-8b's 18 layers' pool
+# flattened (545 blocks a layer); Trinity-Mini's sliding and global layers
+# (2081 blocks); qwen3-32b's 64 heads over its 8 layers (289 blocks).
+@pytest.mark.parametrize("h,kv,pages,max_seq,window", [
+    (H, 8, 18 * 545, 2048, None),
+    (H, T_KV, 2081, 8192, T_W),
+    (H, T_KV, 2081, 8192, None),
+    (64, 8, 8 * 289, 1024, None)])
+def test_paged_prefill_attention_compiles_for_v5e(one_chip, h, kv, pages,
+                                                  max_seq, window):
+    from repro.inference.continuous import _kernel_width
+    nb = _kernel_width(max_seq + 4 * 32, 32, pages)
+    text = _compile_text(
+        lambda q, p, t, n: paged_attention(q, p, t, n, window=window),
+        one_chip, ((B, 32, h, HD), jnp.bfloat16),
+        ((pages, 32, 2 * kv, HD), jnp.bfloat16), ((B, nb), jnp.int32),
+        ((B,), jnp.int32))
+    call = re.search(r'%[-\w.]+ = .*custom_call_target="tpu_custom_call"',
+                     text)
+    assert call and call.group(0).startswith("%ragged_paged_attention")
+    # the expert readers' pattern (moe.*.longdoc) does not take it for a
+    # grouped matmul
+    assert not re.match(r"^%ragged-dot[-\w.]* = ", call.group(0))
+
+
+# ``bytes accessed`` of the compiled decode step below at the commit
+# before the pool held keys and values in one leaf (separate k and v
+# leaves, each gathered with ``jnp.take`` in its default fill mode).
+PARENT_DECODE_BYTES = 4_865_009_664
+
+
+def test_decode_step_gathers_the_pool_without_a_block_loop(one_chip):
+    """qwen3-32b's decode step as the dashboard runs it (8 layers, 8 slots,
+    289 pool blocks, a 32-block gather) compiled for a v5e: the one
+    ``while`` of the program is the layer scan, not a loop over the
+    gathered blocks (which gathering along the pool's block axis of
+    [P, NB, ...] lowers to), and it moves no more bytes than the step with
+    separate key and value leaves did, within 2%."""
+    import importlib.util
+    import json
+    import pathlib
+    import sys
+    import types
+    from repro.inference.continuous import ContinuousBatcher
+    from repro.inference.paged_kv import PagedKVCache
+    from repro.models import model_zoo
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    spec = importlib.util.spec_from_file_location(
+        "qwen3_arch", repo / "chipbench/reference/qwen3.py")
+    qwen3 = importlib.util.module_from_spec(spec)
+    sys.modules["qwen3_arch"] = qwen3       # its dataclasses look it up
+    spec.loader.exec_module(qwen3)
+    conf = json.loads((repo / "chipbench/configs/qwen3-32b.json").read_text())
+    model = model_zoo.build(qwen3.model_config(conf, "qwen3-32b"))
+    kv = PagedKVCache(model, block_size=32, num_blocks=2)
+    batcher = types.SimpleNamespace(model=model, kv=kv, decode_impl="auto")
+
+    def on_chip(x, blocks=None):
+        shape = x.shape
+        if blocks:                          # [P, NB, bs, 2 KV, hd]
+            shape = shape[:1] + (blocks,) + shape[2:]
+        return jax.ShapeDtypeStruct(shape, x.dtype, sharding=one_chip)
+
+    params = jax.tree.map(on_chip, jax.eval_shape(model.init_params,
+                                                  jax.random.PRNGKey(0)))
+    pool = jax.tree.map(lambda x: on_chip(x, 289), kv.pool)
+    i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32, sharding=one_chip)
+
+    def _decode_fn(*args):
+        return ContinuousBatcher._decode_fn(batcher, *args)
+
+    compiled = jax.jit(_decode_fn, donate_argnums=(1,)).lower(
+        params, pool, i32(8, 32), i32(8), i32(8), i32(8, 1)).compile()
+    entry = compiled.as_text().split("\nENTRY", 1)[1]
+    assert len(re.findall(r" while\(", entry)) == 1
+    cost = compiled.cost_analysis()
+    cost = cost[0] if isinstance(cost, list) else cost
+    assert cost["bytes accessed"] <= 1.02 * PARENT_DECODE_BYTES
